@@ -42,11 +42,9 @@ def update_artifact(section: str, payload: dict, path: str = None) -> None:
 
 
 def _sync(out):
-    """Block until every array in ``out`` is materialized on device."""
-    try:
-        return jax.block_until_ready(out)
-    except Exception:  # non-pytree / host-only outputs
-        return out
+    """Block until every array in ``out`` is materialized on device; a
+    failure of the device computation raises here."""
+    return jax.block_until_ready(out)
 
 
 def timed(fn: Callable, repeats: int = 3, warmup: int = 1):

@@ -49,6 +49,7 @@
 from __future__ import annotations
 
 import argparse
+import os
 import sys
 import traceback
 
@@ -85,12 +86,26 @@ MODULE_SECTIONS = {
 }
 
 
+def _configure_jax() -> None:
+    """Compile cache where ``JAX_COMPILATION_CACHE_DIR`` says, else the
+    fixed ``.jax_cache/`` at the repo root; the threefry bit layout the
+    benches' seeded fleets and traces were drawn with."""
+    import jax
+
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".jax_cache"))
+    jax.config.update("jax_threefry_partitionable", False)
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma-separated substrings of bench module names")
     args = ap.parse_args()
     wanted = args.only.split(",") if args.only else None
+    _configure_jax()
 
     print("name,us_per_call,derived")
     failures = 0

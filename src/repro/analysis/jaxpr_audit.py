@@ -30,6 +30,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 from repro.analysis import contracts
 
@@ -83,9 +84,9 @@ def _iter_jaxprs(jaxpr):
 
 
 def _sub_jaxprs(val):
-    if isinstance(val, jax.core.ClosedJaxpr):
+    if isinstance(val, ClosedJaxpr):
         yield val.jaxpr
-    elif isinstance(val, jax.core.Jaxpr):
+    elif isinstance(val, Jaxpr):
         yield val
     elif isinstance(val, (list, tuple)):
         for v in val:
@@ -95,7 +96,7 @@ def _sub_jaxprs(val):
 _ALLOWED_OUT = tuple(sorted(contracts.ALLOWED_OUTPUT_DTYPES))
 
 
-def audit_jaxpr(closed: jax.core.ClosedJaxpr, *, entry: str,
+def audit_jaxpr(closed: ClosedJaxpr, *, entry: str,
                 const_budget: int = contracts.CONST_BYTE_BUDGET,
                 allowed_out_dtypes: Sequence[str] = _ALLOWED_OUT,
                 ) -> EntryAudit:
@@ -248,7 +249,7 @@ def tiny_fleet(n: int = 3):
     return alexnet_fleet(jax.random.PRNGKey(0), n)
 
 
-def _trace_entries(n: int = 3) -> List[Tuple[str, jax.core.ClosedJaxpr]]:
+def _trace_entries(n: int = 3) -> List[Tuple[str, ClosedJaxpr]]:
     """make_jaxpr over the real public entry points at tiny sizes."""
     from repro.core.api import Planner, PlannerConfig, Scenario, stack_scenarios
     from repro.core.ccp import sigma_cantelli
@@ -266,7 +267,7 @@ def _trace_entries(n: int = 3) -> List[Tuple[str, jax.core.ClosedJaxpr]]:
     faults = FaultState.identity()._replace(
         vm_mean_scale=jnp.asarray(3.0, jnp.float64))
 
-    entries: List[Tuple[str, jax.core.ClosedJaxpr]] = []
+    entries: List[Tuple[str, ClosedJaxpr]] = []
 
     def add(name, fn, *args, **kwargs):
         entries.append((name, jax.make_jaxpr(fn, **kwargs)(*args)))

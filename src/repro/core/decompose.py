@@ -58,7 +58,6 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -264,7 +263,7 @@ def _group_programs(mesh, policy: Policy, pccp_iters: int, solver: str,
 
     @jax.jit
     def prep(fleet, m, deadline, eps, B):
-        fn = shard_map(
+        fn = jax.shard_map(
             prep_raw, mesh=mesh,
             in_specs=(_lane_specs(fleet), svec, P("devices"), P("devices"),
                       P()),
@@ -279,7 +278,7 @@ def _group_programs(mesh, policy: Policy, pccp_iters: int, solver: str,
 
     @jax.jit
     def bsum(prep_v, w, B, log_lam, need):
-        fn = shard_map(
+        fn = jax.shard_map(
             bsum_raw, mesh=mesh,
             in_specs=(svec, P("devices"), P(), P(None), P(None)),
             out_specs=P(None))
@@ -292,7 +291,7 @@ def _group_programs(mesh, policy: Policy, pccp_iters: int, solver: str,
 
     @jax.jit
     def solve(prep_v, B, log_lam, need):
-        fn = shard_map(
+        fn = jax.shard_map(
             solve_raw, mesh=mesh,
             in_specs=(svec, P(), P(None), P(None)),
             out_specs=svec)
@@ -842,7 +841,7 @@ def _optimal_programs(mesh, sigma_model: str) -> OptimalPrograms:
 
     @jax.jit
     def prep(fleet, deadline, eps, B):
-        fn = shard_map(
+        fn = jax.shard_map(
             prep_raw, mesh=mesh,
             in_specs=(_lane_specs(fleet), P("devices"), P("devices"), P()),
             out_specs=P("devices", None))
@@ -855,7 +854,7 @@ def _optimal_programs(mesh, sigma_model: str) -> OptimalPrograms:
 
     @jax.jit
     def tables(fleet, budget_all, b_lo_all, feas0_all, B, log_lam, need):
-        fn = shard_map(
+        fn = jax.shard_map(
             tables_raw, mesh=mesh,
             in_specs=(_lane_specs(fleet), P("devices", None),
                       P("devices", None), P("devices", None), P(), P(), P()),
@@ -872,7 +871,7 @@ def _optimal_programs(mesh, sigma_model: str) -> OptimalPrograms:
 
     @jax.jit
     def occ(fleet, cost, feas, budget_all, w, log_mu, need):
-        fn = shard_map(
+        fn = jax.shard_map(
             occ_raw, mesh=mesh,
             in_specs=(_lane_specs(fleet), P("devices", None),
                       P("devices", None), P("devices", None), P("devices"),
@@ -906,7 +905,7 @@ def _optimal_programs(mesh, sigma_model: str) -> OptimalPrograms:
     @jax.jit
     def eval_(fleet, cost, b, f, feas, budget_all, w, deadline, eps, log_mu,
               need):
-        fn = shard_map(
+        fn = jax.shard_map(
             eval_raw, mesh=mesh,
             in_specs=(_lane_specs(fleet), P("devices", None),
                       P("devices", None), P("devices", None),

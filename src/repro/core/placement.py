@@ -321,6 +321,11 @@ def assign_devices_host(occ, caps, strategy: str = "hybrid") -> np.ndarray:
     if caps.ndim != 1:
         raise ValueError(
             f"assign_devices_host needs an (E,) capacity vector, got shape {caps.shape}")
+    # XLA reads subnormal inputs as zero, so the traced strategies see a
+    # subnormal capacity as an absent node; the mirror must too.
+    tiny = np.finfo(np.float64).tiny
+    caps = np.where(np.abs(caps) < tiny, 0.0, caps)
+    occ = np.where(np.abs(occ) < tiny, 0.0, occ)
     return fn(occ, caps)
 
 

@@ -248,6 +248,26 @@ def policy_point_tables(fleet: Fleet, b, f, policy: Policy,
     return e_table, t_table, var_table
 
 
+@jax.jit
+def objective_trace(fleet: Fleet, m, b, f):
+    """``Plan.objective_trace`` from the stacked per-step ``(T, N)`` new
+    partition points ``m`` and the allocation ``(b, f)`` they were chosen
+    at: Σ_n expected device energy per step (true energy, not the
+    μ-priced surrogate). The fused planner and ``planner_ref`` both build
+    their trace with this one compiled function, so the two round alike
+    (XLA may fuse a product into an FMA, which an op-by-op evaluation of
+    the same formula does not)."""
+    def one(m, b, f):
+        sel = select_point(fleet, m)
+        e_loc = energy.expected_local_energy(fleet.platform.kappa,
+                                             sel.w_flops, sel.g_eff, f)
+        e_off = channel.offload_energy(sel.d_bits, b, fleet.link.p_tx,
+                                       fleet.link.gain)
+        return jnp.sum(e_loc + e_off)
+
+    return jax.vmap(one)(m, b, f)
+
+
 def _traced_status(alloc: Allocation, total_energy, margins) -> jnp.ndarray:
     """OK/DEGRADED stamp computed inside the trace (no host syncs): a
     healthy plan has finite allocation, energy and margins. Transient
@@ -536,11 +556,10 @@ def _alternation(fleet: Fleet, deadline, eps, B, edge_cap, m0, policy: Policy,
             m_new, feas, pc = policy.partition(
                 m, e_table + mu * occ_table, t_table, var_table, sigma, deadline,
                 pccp_iters, solver, pccp_gated)
-            # the trace records true energy, not the μ-priced surrogate
-            obj = jnp.sum(jnp.take_along_axis(e_table, m_new[:, None], -1)[:, 0])
-            return (m_new, lam_hi, mu_hi), (obj, pc, feas, mu)
+            return (m_new, lam_hi, mu_hi), ((m_new, alloc.b, alloc.f), pc,
+                                            feas, mu)
 
-        carry, (traces, pccp_trace, feas_seq, mu_seq) = jax.lax.scan(
+        carry, (steps, pccp_trace, feas_seq, mu_seq) = jax.lax.scan(
             step, (m, hi0, hi0), None, length=outer_iters)
         m, lam_hi, _ = carry
         alloc, _ = allocate_with_bracket(
@@ -572,11 +591,11 @@ def _alternation(fleet: Fleet, deadline, eps, B, edge_cap, m0, policy: Policy,
             m_new, feas, pc = policy.partition(
                 m, e_table + mu_dev[:, None] * occ_table, t_table, var_table,
                 sigma, deadline, pccp_iters, solver, pccp_gated)
-            obj = jnp.sum(jnp.take_along_axis(e_table, m_new[:, None], -1)[:, 0])
-            return (m_new, lam_hi, mu_hi), (obj, pc, feas, mu_vec)
+            return (m_new, lam_hi, mu_hi), ((m_new, alloc.b, alloc.f), pc,
+                                            feas, mu_vec)
 
         mu_hi0 = jnp.full((e_count,), _LOG_PRICE_HI0, jnp.float64)
-        carry, (traces, pccp_trace, feas_seq, mu_seq) = jax.lax.scan(
+        carry, (steps, pccp_trace, feas_seq, mu_seq) = jax.lax.scan(
             step, (m, hi0, mu_hi0), None, length=outer_iters)
         m, lam_hi, _ = carry
         occ_final = jnp.take_along_axis(occ_table, m[:, None], -1)[:, 0]
@@ -587,6 +606,7 @@ def _alternation(fleet: Fleet, deadline, eps, B, edge_cap, m0, policy: Policy,
             edge_capacity_s=edge_cap, edge_price=mu_seq[-1],
             prior_log_hi=lam_hi, assignment=assignment, edge_eps=edge_eps)
 
+    traces = objective_trace(fleet, *steps)
     return _plan_tail(fleet, m, alloc, deadline, eps, sig_model, feas_seq[-1],
                       traces, pccp_trace, assignment)
 

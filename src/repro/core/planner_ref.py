@@ -34,6 +34,7 @@ from repro.core.planner import (
     _traced_status,
     default_starts,
     get_policy,
+    objective_trace,
     policy_point_tables,
 )
 from repro.core.resource import allocate, select_point
@@ -92,7 +93,7 @@ def plan_reference(
     if fleet.num_points is not None:  # ragged fleet: clamp starts to M_n
         m = jnp.minimum(m, fleet.num_points - 1)
 
-    traces, pccp_trace = [], []
+    steps, pccp_trace = [], []
     feasible = jnp.ones((n,), bool)
     alloc = None
     for _ in range(outer_iters):
@@ -111,8 +112,7 @@ def plan_reference(
         else:  # robust_exact / gaussian / worst_case → exact enumeration
             m, feasible = _exact_partition(e_table, t_table, var_table, sigma, deadline)
             pccp_trace.append(jnp.ones((n,), jnp.int32))
-        obj = jnp.sum(jnp.take_along_axis(e_table, m[:, None], -1)[:, 0])
-        traces.append(obj)
+        steps.append((m, alloc.b, alloc.f))
 
     alloc = allocate(fleet, m, deadline, eps, B, sig_model, ub_k, channel_cv)
     sel = select_point(fleet, m)
@@ -130,7 +130,8 @@ def plan_reference(
         alloc=alloc,
         total_energy=total_energy,
         feasible=feasible & alloc.feasible,
-        objective_trace=jnp.stack(traces),
+        objective_trace=objective_trace(
+            fleet, *(jnp.stack(x) for x in zip(*steps, strict=True))),
         pccp_iters=jnp.stack(pccp_trace),
         margins=margins,
         status=_traced_status(alloc, total_energy, margins),

@@ -98,7 +98,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool = False,
     n_dev = mesh.size
     t0 = time.time()
 
-    with jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh:
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             step, opt = S.build_train_step(cfg, microbatches=microbatches)
             params, opt_state = S.abstract_state(cfg, opt)

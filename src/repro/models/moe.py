@@ -120,7 +120,6 @@ def moe_apply_a2a(p: Dict, x, *, top_k: int, activation: str,
     back to the GSPMD row-wise path) — e.g. decode steps with seq 1.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     from repro.parallel.sharding import (
         activation_mesh, expert_axis_candidates, fsdp_axes)
@@ -179,7 +178,7 @@ def moe_apply_a2a(p: Dict, x, *, top_k: int, activation: str,
         out = jnp.zeros((t_local, d), xl.dtype).at[tok].add(contrib)
         return out.reshape(xl.shape), aux
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P(fsdp_entry, "model", None), P(None, None),

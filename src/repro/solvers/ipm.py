@@ -261,8 +261,9 @@ def woodbury_solve(d: jnp.ndarray, U: jnp.ndarray, w: jnp.ndarray,
     dinv = 1.0 / d
     y0 = dinv[:, None] * rhs
     if U.shape[1]:
-        M = jnp.diag(1.0 / w) + U.T @ (dinv[:, None] * U)
-        y = y0 - dinv[:, None] * (U @ jnp.linalg.solve(M, U.T @ y0))
+        M = jnp.diag(1.0 / w) + U.T @ (dinv[:, None] * U)  # SPD
+        y = y0 - dinv[:, None] * (U @ jax.scipy.linalg.cho_solve(
+            jax.scipy.linalg.cho_factor(M), U.T @ y0))
     else:
         y = y0
     return y[:, 0] if r.ndim == 1 else y
@@ -425,7 +426,8 @@ def _newton_steps(
             vw = jax.scipy.linalg.cho_solve(
                 c, jnp.concatenate([-g[:, None], A.T], axis=1))
             v, W = vw[:, 0], vw[:, 1:]
-            nu = jnp.linalg.solve(A @ W, A @ v)
+            nu = jax.scipy.linalg.cho_solve(  # A H⁻¹ Aᵀ is SPD
+                jax.scipy.linalg.cho_factor(A @ W), A @ v)
             dz = v - W @ nu
         else:
             c = jax.scipy.linalg.cho_factor(H)

@@ -44,7 +44,8 @@ def levenberg_marquardt(
         J = jax.jacfwd(residual_fn)(x)
         g = J.T @ r
         H = J.T @ J + lam * jnp.eye(x.shape[0], dtype=x.dtype)
-        step = jnp.linalg.solve(H, -g)
+        step = jax.scipy.linalg.cho_solve(  # JᵀJ + λI is SPD
+            jax.scipy.linalg.cho_factor(H), -g)
         x_new = x + step
         f_new = loss(x_new)
         accept = f_new < f_x

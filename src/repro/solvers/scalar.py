@@ -15,6 +15,20 @@ _INV_PHI = 0.6180339887498949  # 1/phi
 _INV_PHI2 = 0.3819660112501051  # 1/phi^2
 
 
+def _match_vma(x, *like):
+    """``x`` marked as varying over every manual mesh axis that ``x`` or
+    any of ``like`` varies over (a no-op outside ``jax.shard_map``).
+
+    A loop carry must leave with the varying axes it entered with; a
+    bracket built from replicated constants (``lo``, ``hi``) is updated
+    from per-device values (``fn(mid)``), so it enters replicated and
+    leaves varying unless it is cast up front.
+    """
+    axes = set().union(*(jax.typeof(y).vma for y in like))
+    return jax.lax.pcast(x, tuple(sorted(axes - jax.typeof(x).vma)),
+                         to="varying")
+
+
 def bisect(fn: Callable, lo, hi, iters: int = 80, endpoint: str = "mid"):
     """Find a root of ``fn`` on [lo, hi] by bisection.
 
@@ -36,6 +50,7 @@ def bisect(fn: Callable, lo, hi, iters: int = 80, endpoint: str = "mid"):
     lo = jnp.asarray(lo, dtype=jnp.float64)
     hi = jnp.asarray(hi, dtype=jnp.float64)
     f_lo = fn(lo)
+    lo, hi = _match_vma(lo, hi, f_lo), _match_vma(hi, lo, f_lo)
 
     def body(_, state):
         lo, hi, f_lo = state
@@ -64,6 +79,8 @@ def golden_section(fn: Callable, lo, hi, iters: int = 72):
     c = a + _INV_PHI2 * h
     d = a + _INV_PHI * h
     fc, fd = fn(c), fn(d)
+    a, b, c, d, fc, fd = (_match_vma(x, a, b, fc, fd)
+                          for x in (a, b, c, d, fc, fd))
 
     def body(_, state):
         a, b, c, d, fc, fd = state

@@ -27,8 +27,7 @@ def test_trip_count_and_flops():
     assert 0.9 * expected <= cost.flops <= 1.6 * expected, (cost.flops, expected)
     # XLA's own cost analysis undercounts the loop body (the reason this
     # module exists): it must be ≈ L× below ours.
-    ca = compiled.cost_analysis()  # list-of-dicts on jax 0.4.x, dict on 0.5+
-    xla = (ca[0] if isinstance(ca, (list, tuple)) else ca)["flops"]
+    xla = compiled.cost_analysis()["flops"]
     assert cost.flops > 2.0 * xla
 
 

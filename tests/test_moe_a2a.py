@@ -16,34 +16,29 @@ SCRIPT = textwrap.dedent("""
     from repro.models.moe import moe_init, moe_apply
     from repro.parallel import sharding as shd
 
-    try:  # axis_types only exists on newer jax (>= 0.5)
-        mesh = jax.make_mesh((2, 4), ("data", "model"),
-                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
-    except (TypeError, AttributeError):
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     shd.set_activation_mesh(mesh)
     key = jax.random.PRNGKey(0)
-    ctx = jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
 
     # E = 8 = 2*4 (full expert axes) and E = 4 (model-only)
     for e, shared in ((8, 1), (4, 0)):
         p = moe_init(key, 32, e, 64, shared, 48, jnp.float32)
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 32))
-        with ctx:
+        with jax.set_mesh(mesh):
             oa, _ = jax.jit(lambda p, x: moe_apply(
                 p, x, top_k=2, capacity_factor=16.0, dispatch="a2a"))(p, x)
         od, _ = moe_apply(p, x, top_k=2, capacity_factor=16.0, dispatch="dense")
         err = float(jnp.abs(oa - od).max())
         assert err < 1e-4, (e, err)
 
-        # The loss touches BOTH outputs: on jax 0.4.x a purely-unused aux
-        # output gets a symbolic Zero cotangent that the shard_map pmean
-        # transpose cannot handle ('Zero' has no attribute 'reshape').
+        # The loss touches BOTH outputs, so the gradient flows through the
+        # aux pmean as well as the token exchange.
         def loss(p):
             out, aux = jax.jit(lambda p, x: moe_apply(
                 p, x, top_k=2, capacity_factor=16.0, dispatch="a2a"))(p, x)
             return jnp.sum(out ** 2) + 0.0 * aux
-        with ctx:
+        with jax.set_mesh(mesh):
             g = jax.grad(loss)(p)
         assert all(bool(jnp.isfinite(v).all()) for v in jax.tree.leaves(g)), e
     print("A2A_OK")

@@ -162,6 +162,19 @@ def test_host_traced_bit_identity_property(occ, caps, strat):
         assign_devices_host(occ, caps, strat))
 
 
+@pytest.mark.parametrize("strat", list(STRATEGIES))
+def test_host_traced_bit_identity_subnormal_caps(strat):
+    """XLA flushes subnormal inputs to zero, so a subnormal capacity is an
+    absent node to the traced strategies; the host mirror must agree
+    (a hypothesis counter-example: caps=[0, 5e-324])."""
+    occ = np.asarray([0.5, 1.0, 2.0], np.float64)
+    for caps in ([0.0, 5e-324], [5e-324, 1.0, 0.0], [1.0, 1e-310]):
+        caps = np.asarray(caps, np.float64)
+        np.testing.assert_array_equal(
+            np.asarray(assign_devices(occ, caps, strat)),
+            assign_devices_host(occ, caps, strat), err_msg=str(caps))
+
+
 # ------------------------------------------------------ planned E>1 plans
 
 
