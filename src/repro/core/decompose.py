@@ -500,8 +500,8 @@ def _host_bisect(fn, lo, hi, iters=60, endpoint="mid"):  # analyze: ok(TRC001,TR
     return hi if endpoint == "hi" else 0.5 * (lo + hi)
 
 
-def _host_expand(fn, hi_start=None, size=1):  # analyze: ok(TRC001,TRC002,TRC003): host-level global price loop by design (numpy replica of resource._expand_log_bracket)
-    """Per-lane ``resource._expand_log_bracket`` in numpy float64:
+def _host_expand(fn, hi_start=None, size=1):  # analyze: ok(TRC001,TRC002,TRC003): host-level global price loop by design (numpy replica of resource.price_search)
+    """Per-lane bracket expansion of ``resource.price_search`` in numpy float64:
     warm-start snap to the expansion grid, contract while the next-lower
     grid point clears, then the standard upward expansion. Masked per-lane
     updates replicate the vmapped while_loop batching rule (inactive lanes
@@ -546,7 +546,7 @@ def _lam_clear(programs, groups, preps, B_dev, B_host, S, lam_hi):  # analyze: o
     sum of per-group device-evaluated partials at the same price. Returns
     ``(log_lam, need, lam_hi)`` with the expanded bracket top threaded for
     the next alternation step (warm-start is value-invariant, see
-    ``resource._expand_log_bracket``). When no start lane needs pricing
+    ``resource.price_search``). When no start lane needs pricing
     (Σ b(0) ≤ B everywhere) the search is skipped outright — λ = 0
     regardless, exactly what the monolithic ``where(need, …, 0)`` yields.
     """

@@ -92,6 +92,18 @@ def _sample_matched(key, dist: str, mean, var, shape):
     raise ValueError(f"unknown dist {dist!r}")
 
 
+def sample_local_and_vm(key_loc, key_vm, dist: str, mean_loc, var_loc,
+                        mean_vm, var_vm, shape):
+    """``_sample_matched`` for the local and the VM tier, each from its
+    own key: one vmapped call over the pair, so the compiled program holds
+    one copy of the sampler (the gamma sampler's rejection loop is most of
+    a validation program's code). Each tier's samples are those its own
+    ``_sample_matched(key, ...)`` call draws."""
+    return jax.vmap(lambda k, m, v: _sample_matched(k, dist, m, v, shape))(
+        jnp.stack([key_loc, key_vm]), jnp.stack([mean_loc, mean_vm]),
+        jnp.stack([var_loc, var_vm]))
+
+
 @partial(jax.jit, static_argnames=("dist", "num_samples", "channel_cv"))
 def violation_report(
     key,
@@ -181,16 +193,11 @@ def violation_report(
         t_off = channel.offload_time(sel.d_bits, alloc.b, fleet.link.p_tx,
                                      gain)[None, :]
     shape = (num_samples, n)
-    t_loc = jnp.where(
-        sel.w_flops[None, :] > 0,
-        _sample_matched(k_loc, dist, mean_loc, var_scale * sel.v_loc, shape),
-        0.0,
-    )
-    t_vm = jnp.where(
-        sel.t_vm[None, :] > 0,
-        _sample_matched(k_vm, dist, sel.t_vm, var_scale * sel.v_vm, shape),
-        0.0,
-    )
+    s_loc, s_vm = sample_local_and_vm(k_loc, k_vm, dist, mean_loc,
+                                      var_scale * sel.v_loc, sel.t_vm,
+                                      var_scale * sel.v_vm, shape)
+    t_loc = jnp.where(sel.w_flops[None, :] > 0, s_loc, 0.0)
+    t_vm = jnp.where(sel.t_vm[None, :] > 0, s_vm, 0.0)
     if faults is not None:
         # Straggler bursts: keys derived by fold_in so the 3-way split
         # above (and hence the no-fault sample stream) stays unchanged.

@@ -51,16 +51,14 @@ from repro.core.pccp import pccp_partition
 from repro.core.resource import (
     _EDGE_CAP_RTOL,
     _LOG_PRICE_HI0,
-    _LOG_PRICE_LO,
     Allocation,
     _device_best_b_at,
     _device_invariants,
-    _expand_log_bracket,
     allocate,
     allocate_with_bracket,
+    price_search,
     select_point,
 )
-from repro.solvers.scalar import bisect
 
 
 #: ``Plan.status`` codes (DESIGN.md §robustness — the solver fail-soft
@@ -296,7 +294,7 @@ def _clearing_price(occ_at, edge_cap, prior_log_hi=None):
     """Smallest price μ ≥ 0 with ``occ_at(μ) ≤ edge_cap``; returns
     ``(μ, log_hi)`` where ``log_hi`` is the expanded bracket top (for
     warm-starting the next clearing — value-identical, see
-    ``resource._expand_log_bracket``).
+    ``resource.price_search``).
 
     ``occ_at`` must be a non-increasing step function of μ (a priced
     argmin's selected occupancy). The search is a log-space bisection
@@ -305,13 +303,9 @@ def _clearing_price(occ_at, edge_cap, prior_log_hi=None):
     sits on the feasible side of the step. Complementary slackness:
     μ = 0 when the unpriced selection already fits.
     """
-    need = occ_at(jnp.asarray(0.0, jnp.float64)) > edge_cap
-
-    def occ_excess(log_mu):
-        return occ_at(10.0**log_mu) - edge_cap
-
-    log_hi, _ = _expand_log_bracket(occ_excess, hi_start=prior_log_hi)
-    log_mu = bisect(occ_excess, _LOG_PRICE_LO, log_hi, iters=60, endpoint="hi")
+    _, log_mu, need, log_hi = price_search(
+        occ_at, lambda occ: occ, edge_cap, hi_start=prior_log_hi,
+        endpoint="hi", final=False)
     return jnp.where(need, 10.0**log_mu * _MU_SAFETY, 0.0), log_hi
 
 
@@ -536,41 +530,51 @@ def _alternation(fleet: Fleet, deadline, eps, B, edge_cap, m0, policy: Policy,
     m = jnp.broadcast_to(jnp.asarray(m0, jnp.int32), (n,))
     hi0 = jnp.asarray(_LOG_PRICE_HI0, jnp.float64)
 
+    def last_or(i, repartition, m, mu_hi):
+        """``repartition(mu_hi)`` except on the extra last step, which only
+        allocates (its partition outputs are dropped below)."""
+        out = jax.eval_shape(repartition, mu_hi)
+        return jax.lax.cond(
+            i < outer_iters, repartition,
+            lambda mu_hi: (m, mu_hi, *(jnp.zeros(t.shape, t.dtype)
+                                       for t in out[2:])), mu_hi)
+
+    # outer_iters alternation steps plus one that only allocates at the
+    # final m: the program holds one copy of the allocation, not two
     if edge_cap.ndim == 0:  # one shared edge (scalar μ — the seed goldens)
-        def step(carry, _):
+        def step(carry, i):
             m, lam_hi, mu_hi = carry
             alloc, lam_hi = allocate_with_bracket(
                 fleet, m, deadline, eps, B, sig_model, ub_k, channel_cv,
                 edge_capacity_s=edge_cap, prior_log_hi=lam_hi,
                 edge_eps=edge_eps)
-            e_table, t_table, var_table = policy_point_tables(
-                fleet, alloc.b, alloc.f, policy, channel_cv)
-            if policy.edge_aware:
-                mu, mu_hi = _edge_clearing_price(e_table, t_table, var_table,
-                                                 sigma, deadline, occ_table,
-                                                 edge_cap, prior_log_hi=mu_hi,
-                                                 occ_var=occ_var,
-                                                 edge_sigma=edge_sig)
-            else:
-                mu = jnp.asarray(0.0, jnp.float64)
-            m_new, feas, pc = policy.partition(
-                m, e_table + mu * occ_table, t_table, var_table, sigma, deadline,
-                pccp_iters, solver, pccp_gated)
-            return (m_new, lam_hi, mu_hi), ((m_new, alloc.b, alloc.f), pc,
-                                            feas, mu)
 
-        carry, (steps, pccp_trace, feas_seq, mu_seq) = jax.lax.scan(
-            step, (m, hi0, hi0), None, length=outer_iters)
-        m, lam_hi, _ = carry
-        alloc, _ = allocate_with_bracket(
-            fleet, m, deadline, eps, B, sig_model, ub_k, channel_cv,
-            edge_capacity_s=edge_cap, edge_price=mu_seq[-1],
-            prior_log_hi=lam_hi, edge_eps=edge_eps)
+            def repartition(mu_hi):
+                e_table, t_table, var_table = policy_point_tables(
+                    fleet, alloc.b, alloc.f, policy, channel_cv)
+                if policy.edge_aware:
+                    mu, mu_hi = _edge_clearing_price(
+                        e_table, t_table, var_table, sigma, deadline,
+                        occ_table, edge_cap, prior_log_hi=mu_hi,
+                        occ_var=occ_var, edge_sigma=edge_sig)
+                else:
+                    mu = jnp.asarray(0.0, jnp.float64)
+                m_new, feas, pc = policy.partition(
+                    m, e_table + mu * occ_table, t_table, var_table, sigma,
+                    deadline, pccp_iters, solver, pccp_gated)
+                return m_new, mu_hi, feas, pc, mu
+
+            m_new, mu_hi, feas, pc, mu = last_or(i, repartition, m, mu_hi)
+            return (m_new, lam_hi, mu_hi), ((m_new, alloc.b, alloc.f), pc,
+                                            feas, mu, alloc)
+
+        carry, (steps, pccp_trace, feas_seq, mu_seq, allocs) = jax.lax.scan(
+            step, (m, hi0, hi0), jnp.arange(outer_iters + 1))
         assignment = jnp.zeros((n,), jnp.int32)
     else:  # per-node capacities: assignment + per-node prices
         e_count = edge_cap.shape[0]
 
-        def step(carry, _):
+        def step(carry, i):
             m, lam_hi, mu_hi = carry
             occ_now = jnp.take_along_axis(occ_table, m[:, None], -1)[:, 0]
             assign = placement.assign_devices(occ_now, edge_cap, policy.assign)
@@ -578,34 +582,39 @@ def _alternation(fleet: Fleet, deadline, eps, B, edge_cap, m0, policy: Policy,
                 fleet, m, deadline, eps, B, sig_model, ub_k, channel_cv,
                 edge_capacity_s=edge_cap, prior_log_hi=lam_hi,
                 assignment=assign, edge_eps=edge_eps)
-            e_table, t_table, var_table = policy_point_tables(
-                fleet, alloc.b, alloc.f, policy, channel_cv)
-            if policy.edge_aware:
-                mu_vec, mu_hi = _node_clearing_prices(
-                    e_table, t_table, var_table, sigma, deadline, occ_table,
-                    assign, edge_cap, prior_log_hi=mu_hi, occ_var=occ_var,
-                    edge_sigma=edge_sig)
-            else:
-                mu_vec = jnp.zeros((e_count,), jnp.float64)
-            mu_dev = mu_vec[assign]  # each device pays its own node's price
-            m_new, feas, pc = policy.partition(
-                m, e_table + mu_dev[:, None] * occ_table, t_table, var_table,
-                sigma, deadline, pccp_iters, solver, pccp_gated)
+
+            def repartition(mu_hi):
+                e_table, t_table, var_table = policy_point_tables(
+                    fleet, alloc.b, alloc.f, policy, channel_cv)
+                if policy.edge_aware:
+                    mu_vec, mu_hi = _node_clearing_prices(
+                        e_table, t_table, var_table, sigma, deadline,
+                        occ_table, assign, edge_cap, prior_log_hi=mu_hi,
+                        occ_var=occ_var, edge_sigma=edge_sig)
+                else:
+                    mu_vec = jnp.zeros((e_count,), jnp.float64)
+                mu_dev = mu_vec[assign]  # each device pays its own node's price
+                m_new, feas, pc = policy.partition(
+                    m, e_table + mu_dev[:, None] * occ_table, t_table,
+                    var_table, sigma, deadline, pccp_iters, solver,
+                    pccp_gated)
+                return m_new, mu_hi, feas, pc, mu_vec
+
+            m_new, mu_hi, feas, pc, mu_vec = last_or(i, repartition, m, mu_hi)
             return (m_new, lam_hi, mu_hi), ((m_new, alloc.b, alloc.f), pc,
-                                            feas, mu_vec)
+                                            feas, mu_vec, (alloc, assign))
 
         mu_hi0 = jnp.full((e_count,), _LOG_PRICE_HI0, jnp.float64)
-        carry, (steps, pccp_trace, feas_seq, mu_seq) = jax.lax.scan(
-            step, (m, hi0, mu_hi0), None, length=outer_iters)
-        m, lam_hi, _ = carry
-        occ_final = jnp.take_along_axis(occ_table, m[:, None], -1)[:, 0]
-        assignment = placement.assign_devices(occ_final, edge_cap,
-                                              policy.assign)
-        alloc, _ = allocate_with_bracket(
-            fleet, m, deadline, eps, B, sig_model, ub_k, channel_cv,
-            edge_capacity_s=edge_cap, edge_price=mu_seq[-1],
-            prior_log_hi=lam_hi, assignment=assignment, edge_eps=edge_eps)
+        carry, (steps, pccp_trace, feas_seq, mu_seq, (allocs, assigns)) = (
+            jax.lax.scan(step, (m, hi0, mu_hi0), jnp.arange(outer_iters + 1)))
+        assignment = assigns[-1]
 
+    m = carry[0]
+    steps, pccp_trace, feas_seq, mu_seq = jax.tree_util.tree_map(
+        lambda x: x[:outer_iters], (steps, pccp_trace, feas_seq, mu_seq))
+    # the last step's allocation is the plan's; it records the last price
+    alloc = jax.tree_util.tree_map(lambda x: x[-1], allocs)._replace(
+        mu=mu_seq[-1])
     traces = objective_trace(fleet, *steps)
     return _plan_tail(fleet, m, alloc, deadline, eps, sig_model, feas_seq[-1],
                       traces, pccp_trace, assignment)
@@ -876,17 +885,9 @@ def plan_optimal(fleet: Fleet, deadline, eps, B, sigma_model: str = "cantelli",
             return (m_sel, pick(b), pick(f), pick(e), pick(feas) & any_feas,
                     mu_vec, a)
 
-    _, b0, *_ = solve_at(jnp.asarray(0.0, jnp.float64))
-    need_price = jnp.sum(b0) > B
-
-    def excess(log_lam):
-        _, b, *_ = solve_at(10.0**log_lam)
-        return jnp.sum(b) - B
-
-    log_hi, _ = _expand_log_bracket(excess)
-    log_lam = bisect(excess, _LOG_PRICE_LO, log_hi, iters=60)
+    (m_sel, b, f, e, feas, mu, assignment), log_lam, need_price, _ = (
+        price_search(solve_at, lambda out: jnp.sum(out[1]), B))
     lam = jnp.where(need_price, 10.0**log_lam, 0.0)
-    m_sel, b, f, e, feas, mu, assignment = solve_at(lam)
     # primal capacity check at the rounded discrete selection
     if edge_cap.ndim == 0:
         feas = feas & (occ_of(m_sel) <= edge_cap * (1.0 + _EDGE_CAP_RTOL))

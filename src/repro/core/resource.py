@@ -57,63 +57,124 @@ _LOG_PRICE_STEP = 4.0
 _EDGE_CAP_RTOL = 1e-9
 
 
-def _expand_log_bracket(excess_fn, hi_start=None):
-    """Adaptively raise the log-price bracket top until the excess changes
-    sign. Returns ``(hi, f_hi)``; ``f_hi > 0`` after expansion means even
-    the max price cannot clear the constraint (⇒ infeasible). The common
-    case (``excess(HI0) ≤ 0``) costs one extra evaluation and leaves the
-    seed bracket — and therefore the bisection trajectory — unchanged.
+#: phases of ``price_search``'s loop, each naming what its next
+#: evaluation is for
+(_PH_ZERO, _PH_START, _PH_PROBE, _PH_EXPAND, _PH_BISECT_LO, _PH_BISECT,
+ _PH_FINAL, _PH_DONE) = range(8)
 
-    ``hi_start`` (traced scalar, optional) warm-starts the search from a
-    prior bracket top — e.g. the previous Algorithm-2 step's result. It is
-    snapped to the expansion grid ``HI0 + k·STEP`` (the only values a cold
-    expansion can produce; all grid points are exact in float64), then
-    *contracted* while the next-lower grid point still clears and expanded
-    as usual. Because the excess is monotone non-increasing in the price,
-    both directions terminate at the same grid point a cold expansion
-    finds, so the warm path is **value-identical** to cold-start — it just
-    spends its evaluations near the answer instead of walking up from HI0.
+
+def price_search(solve_at, total, cap, hi_start=None, iters: int = 60,
+                 endpoint: str = "mid", final: bool = True):
+    """Smallest dual price ``p ≥ 0`` with ``total(solve_at(p)) ≤ cap``, for
+    a ``total`` non-increasing in the price. Returns
+    ``(out, log_p, need, log_hi)``.
+
+    The search runs in log10 space, one evaluation per step of a single
+    ``lax.while_loop`` — so the compiled program holds one copy of
+    ``solve_at``, however many stages the search has:
+
+    1. ``need = total(solve_at(0)) > cap`` (complementary slackness: the
+       price is 0 when the unpriced solve already fits);
+    2. the bracket top ``log_hi`` rises from ``_LOG_PRICE_HI0`` in
+       ``_LOG_PRICE_STEP`` steps until the excess
+       ``total(solve_at(10**x)) - cap`` is ≤ 0 (or ``_LOG_PRICE_HI_MAX``
+       is reached: then even the max price cannot clear, and the caller
+       sees an infeasible solve). ``hi_start`` (traced, optional)
+       warm-starts it from a prior bracket top: snapped to the grid
+       ``HI0 + k·STEP`` (the only values a cold expansion can produce, all
+       exact in float64), then *contracted* while the next-lower grid
+       point still clears and expanded as usual. The excess is monotone,
+       so both directions stop at the grid point a cold expansion finds:
+       the warm path is value-identical, it only spends its evaluations
+       near the answer;
+    3. ``iters`` bisection steps on ``[_LOG_PRICE_LO, log_hi]``; ``log_p``
+       is the final bracket's midpoint, or its upper end with
+       ``endpoint="hi"`` (for a step-function excess the upper end stays
+       on the ``excess ≤ 0`` side);
+    4. with ``final``, ``out = solve_at(where(need, 10**log_p, 0))`` (else
+       ``out`` is None).
     """
+    if endpoint not in ("mid", "hi"):
+        raise ValueError(f"endpoint must be 'mid' or 'hi', got {endpoint!r}")
     hi0 = jnp.asarray(_LOG_PRICE_HI0, jnp.float64)
-
     if hi_start is None:
-        start, f_start = hi0, excess_fn(hi0)
+        start = hi0
     else:
         k = jnp.round((jnp.asarray(hi_start, jnp.float64) - hi0)
                       / _LOG_PRICE_STEP)
         k_max = (_LOG_PRICE_HI_MAX - _LOG_PRICE_HI0) // _LOG_PRICE_STEP
         start = hi0 + jnp.clip(k, 0.0, k_max) * _LOG_PRICE_STEP
-        f_start = excess_fn(start)
+    zero = jnp.asarray(0.0, jnp.float64)
+    lo0 = jnp.asarray(_LOG_PRICE_LO, jnp.float64)
+    out0 = jax.tree_util.tree_map(
+        lambda t: jnp.zeros(t.shape, t.dtype), jax.eval_shape(solve_at, zero))
+    state = dict(phase=jnp.asarray(_PH_ZERO, jnp.int32),
+                 k=jnp.asarray(0, jnp.int32), x=zero, hi=start, f_hi=zero,
+                 lo=lo0, b_hi=start, f_lo=zero, need=jnp.asarray(False),
+                 out=out0)
+    bisect_end = ((lambda lo, hi: hi) if endpoint == "hi"
+                  else (lambda lo, hi: 0.5 * (lo + hi)))
 
-        # Contract: while the grid point one step down still clears
-        # (excess ≤ 0), move down. Carries (hi, f_hi, f_dn) where f_dn is
-        # the excess one step below hi (a sentinel +1 at the grid floor).
-        def probe_down(hi):
-            return jnp.where(hi > hi0 + 1e-9, excess_fn(hi - _LOG_PRICE_STEP),
-                             1.0)
+    def expand_or_bisect(st):
+        """The bracket top's excess is known: step the top up, or start
+        the bisection at its lower end."""
+        go = (st["f_hi"] > 0.0) & (st["hi"] < _LOG_PRICE_HI_MAX - 1e-9)
+        hi = jnp.where(go, st["hi"] + _LOG_PRICE_STEP, st["hi"])
+        return dict(st, hi=hi, b_hi=hi, lo=lo0,
+                    phase=jnp.where(go, _PH_EXPAND, _PH_BISECT_LO),
+                    x=jnp.where(go, hi, lo0))
 
-        def c_cond(state):
-            hi, _, f_dn = state
-            return (hi > hi0 + 1e-9) & (f_dn <= 0.0)
+    def body(st):
+        ph, x = st["phase"], st["x"]
+        price = jnp.where(ph == _PH_FINAL,
+                          jnp.where(st["need"], 10.0**x, 0.0),
+                          jnp.where(ph == _PH_ZERO, 0.0, 10.0**x))
+        out = solve_at(price)
+        tot = total(out)
+        f = tot - cap
+        nxt = {_PH_ZERO: dict(st, need=tot > cap, phase=_PH_START, x=start)}
+        started = dict(st, hi=start, f_hi=f)
+        nxt[_PH_START] = (
+            expand_or_bisect(started) if hi_start is None else
+            dict(started, phase=_PH_PROBE, x=start - _LOG_PRICE_STEP))
+        # contraction: f is the excess one grid step below the top
+        above = st["hi"] > hi0 + 1e-9
+        f_dn = jnp.where(above, f, 1.0)
+        hi_dn = st["hi"] - _LOG_PRICE_STEP
+        nxt[_PH_PROBE] = jax.tree_util.tree_map(
+            partial(jnp.where, above & (f_dn <= 0.0)),
+            dict(st, hi=hi_dn, f_hi=f_dn, phase=_PH_PROBE,
+                 x=hi_dn - _LOG_PRICE_STEP),
+            expand_or_bisect(st))
+        nxt[_PH_EXPAND] = expand_or_bisect(dict(st, f_hi=f))
+        nxt[_PH_BISECT_LO] = dict(st, f_lo=f, phase=_PH_BISECT, k=0,
+                                  x=0.5 * (st["lo"] + st["b_hi"]))
+        right = jnp.sign(f) == jnp.sign(st["f_lo"])
+        lo = jnp.where(right, x, st["lo"])
+        b_hi = jnp.where(right, st["b_hi"], x)
+        more = st["k"] + 1 < iters
+        nxt[_PH_BISECT] = dict(
+            st, lo=lo, b_hi=b_hi, k=st["k"] + 1,
+            f_lo=jnp.where(right, f, st["f_lo"]),
+            phase=jnp.where(more, _PH_BISECT,
+                            _PH_FINAL if final else _PH_DONE),
+            x=jnp.where(more, 0.5 * (lo + b_hi), bisect_end(lo, b_hi)))
+        nxt[_PH_FINAL] = dict(st, out=out, phase=_PH_DONE)
 
-        def c_body(state):
-            hi, _, f_dn = state
-            hi = hi - _LOG_PRICE_STEP
-            return hi, f_dn, probe_down(hi)
+        def pick(*leaves):  # the next state of the phase that ran
+            chosen = leaves[-1]
+            for i in range(len(leaves) - 2, -1, -1):
+                chosen = jnp.where(ph == i, leaves[i], chosen)
+            return chosen
 
-        start, f_start, _ = jax.lax.while_loop(
-            c_cond, c_body, (start, f_start, probe_down(start)))
+        return jax.tree_util.tree_map(pick, *(
+            jax.tree_util.tree_map(lambda a, ref: jnp.asarray(a, ref.dtype),
+                                   nxt[i], st)
+            for i in range(_PH_DONE)))
 
-    def cond(state):
-        hi, f_hi = state
-        return (f_hi > 0.0) & (hi < _LOG_PRICE_HI_MAX - 1e-9)
-
-    def body(state):
-        hi, _ = state
-        hi = hi + _LOG_PRICE_STEP
-        return hi, excess_fn(hi)
-
-    return jax.lax.while_loop(cond, body, (start, f_start))
+    st = jax.lax.while_loop(lambda st: st["phase"] != _PH_DONE, body, state)
+    out = st["out"] if final else None  # analyze: ok(TRC003): ``final`` is a static Python flag
+    return out, st["x"], st["need"], st["hi"]
 
 
 class Selected(NamedTuple):
@@ -385,23 +446,10 @@ def _allocate_impl(fleet, m_sel, deadline, eps, B, sigma_model, ub_k,
     prep = _alloc_prep(fleet, m_sel, deadline, eps, B, sigma_model, ub_k,
                        channel_cv)
 
-    def solve_at(lam):
-        return _alloc_solve_at(prep, B, lam, channel_cv)
-
-    b0, _, _ = solve_at(jnp.asarray(0.0, jnp.float64))
-    need_price = jnp.sum(b0) > B
-
-    def excess(log_lam):
-        b, _, _ = solve_at(10.0**log_lam)
-        return jnp.sum(b) - B
-
-    # Expand the bracket top until the excess changes sign: the seed's
-    # fixed [1e-16, 1e2] bracket silently pinned λ at 100 on bandwidth-
-    # starved scenarios and let the rescale mask the unmet budget.
-    log_hi, _ = _expand_log_bracket(excess, hi_start=prior_log_hi)
-    log_lam = bisect(excess, _LOG_PRICE_LO, log_hi, iters=60)
+    (b, f, feas), log_lam, need_price, log_hi = price_search(
+        lambda lam: _alloc_solve_at(prep, B, lam, channel_cv),
+        lambda out: jnp.sum(out[0]), B, hi_start=prior_log_hi)
     lam = jnp.where(need_price, 10.0**log_lam, 0.0)
-    b, f, feas = solve_at(lam)
     alloc = _alloc_finalize(prep, b, f, feas, B, lam, need_price, channel_cv,
                             edge_capacity_s, edge_price, assignment, edge_eps)
     return alloc, log_hi
@@ -437,7 +485,7 @@ def allocate(
 
     ``prior_log_hi`` (traced scalar, optional) warm-starts the λ-bracket
     expansion from a prior solve's bracket top — value-identical to a
-    cold start (see ``_expand_log_bracket``). Use ``allocate_with_bracket``
+    cold start (see ``price_search``). Use ``allocate_with_bracket``
     to also get the bracket top back for threading.
 
     ``edge_capacity_s`` may also be a per-node ``(E,)`` capacity vector

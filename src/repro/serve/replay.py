@@ -59,7 +59,7 @@ import numpy as np
 from repro.core import channel, energy
 from repro.core.api import Planner, Scenario
 from repro.core.blocks import Fleet
-from repro.core.montecarlo import _sample_matched
+from repro.core.montecarlo import _sample_matched, sample_local_and_vm
 from repro.core.placement import assignment_churn, migration_energy
 from repro.core.planner import plan_fixed_partition
 from repro.core.resource import Allocation, select_point
@@ -306,18 +306,11 @@ def sample_epoch(
     t_off = channel.offload_time(sel.d_bits, alloc.b, fleet.link.p_tx, gain)
     shape = dev.shape
     k_loc, k_vm = jax.random.split(key, 2)
-    t_loc_r = jnp.where(
-        sel.w_flops[dev] > 0,
-        _sample_matched(k_loc, dist, mean_loc[dev],
-                        var_scale * sel.v_loc[dev], shape),
-        0.0,
-    )
-    t_vm_r = jnp.where(
-        sel.t_vm[dev] > 0,
-        _sample_matched(k_vm, dist, sel.t_vm[dev],
-                        var_scale * sel.v_vm[dev], shape),
-        0.0,
-    )
+    s_loc, s_vm = sample_local_and_vm(
+        k_loc, k_vm, dist, mean_loc[dev], var_scale * sel.v_loc[dev],
+        sel.t_vm[dev], var_scale * sel.v_vm[dev], shape)
+    t_loc_r = jnp.where(sel.w_flops[dev] > 0, s_loc, 0.0)
+    t_vm_r = jnp.where(sel.t_vm[dev] > 0, s_vm, 0.0)
     if faults is not None:
         # Straggler bursts, keyed exactly as violation_report keys them
         # (fold_in 0x57) so the fault taxonomy stays one seeded family.

@@ -24,7 +24,8 @@ def _match_vma(x, *like):
     from per-device values (``fn(mid)``), so it enters replicated and
     leaves varying unless it is cast up front.
     """
-    axes = set().union(*(jax.typeof(y).vma for y in like))
+    axes = set().union(*(getattr(y, "vma", None) or jax.typeof(y).vma
+                         for y in like))
     return jax.lax.pcast(x, tuple(sorted(axes - jax.typeof(x).vma)),
                          to="varying")
 
@@ -49,20 +50,24 @@ def bisect(fn: Callable, lo, hi, iters: int = 80, endpoint: str = "mid"):
         raise ValueError(f"endpoint must be 'mid' or 'hi', got {endpoint!r}")
     lo = jnp.asarray(lo, dtype=jnp.float64)
     hi = jnp.asarray(hi, dtype=jnp.float64)
-    f_lo = fn(lo)
-    lo, hi = _match_vma(lo, hi, f_lo), _match_vma(hi, lo, f_lo)
+    f_type = jax.eval_shape(fn, lo)
+    f_lo = jnp.zeros(f_type.shape, f_type.dtype)
+    lo, hi, f_lo = (_match_vma(x, lo, hi, f_type) for x in (lo, hi, f_lo))
 
-    def body(_, state):
+    # Step 0 evaluates fn(lo); steps 1..iters bisect. One loop, so the
+    # program holds a single copy of ``fn``.
+    def body(i, state):
         lo, hi, f_lo = state
+        first = i == 0
         mid = 0.5 * (lo + hi)
-        f_mid = fn(mid)
-        go_right = jnp.sign(f_mid) == jnp.sign(f_lo)
-        new_lo = jnp.where(go_right, mid, lo)
-        new_f_lo = jnp.where(go_right, f_mid, f_lo)
-        new_hi = jnp.where(go_right, hi, mid)
+        f_x = fn(jnp.where(first, lo, mid))
+        go_right = jnp.sign(f_x) == jnp.sign(f_lo)
+        new_lo = jnp.where(go_right & ~first, mid, lo)
+        new_f_lo = jnp.where(first | go_right, f_x, f_lo)
+        new_hi = jnp.where(go_right | first, hi, mid)
         return new_lo, new_hi, new_f_lo
 
-    lo, hi, _ = jax.lax.fori_loop(0, iters, body, (lo, hi, f_lo))
+    lo, hi, _ = jax.lax.fori_loop(0, iters + 1, body, (lo, hi, f_lo))
     return hi if endpoint == "hi" else 0.5 * (lo + hi)
 
 
@@ -74,28 +79,23 @@ def golden_section(fn: Callable, lo, hi, iters: int = 72):
     """
     lo = jnp.asarray(lo, dtype=jnp.float64)
     hi = jnp.asarray(hi, dtype=jnp.float64)
-    a, b = lo, hi
-    h = b - a
-    c = a + _INV_PHI2 * h
-    d = a + _INV_PHI * h
-    fc, fd = fn(c), fn(d)
-    a, b, c, d, fc, fd = (_match_vma(x, a, b, fc, fd)
-                          for x in (a, b, c, d, fc, fd))
+    f_type = jax.eval_shape(fn, lo)
+    a, b = _match_vma(lo, hi, f_type), _match_vma(hi, lo, f_type)
 
     def body(_, state):
-        a, b, c, d, fc, fd = state
-        shrink_right = fc < fd
-        new_b = jnp.where(shrink_right, d, b)
-        new_a = jnp.where(shrink_right, a, c)
-        h = new_b - new_a
-        new_c = new_a + _INV_PHI2 * h
-        new_d = new_a + _INV_PHI * h
+        a, b = state
+        h = b - a
+        c = a + _INV_PHI2 * h
+        d = a + _INV_PHI * h
         # Only one of (c, d) needs re-evaluation per iteration in the
-        # classic scheme; recomputing both keeps the state static-shaped
-        # and fn is cheap in our uses (closed-form energy expressions).
-        return new_a, new_b, new_c, new_d, fn(new_c), fn(new_d)
+        # classic scheme; evaluating both keeps the state static-shaped,
+        # and one vmapped call of ``fn`` on the pair keeps the program to
+        # a single copy of ``fn``.
+        fc, fd = jax.vmap(fn)(jnp.stack([c, d]))
+        shrink_right = fc < fd
+        return jnp.where(shrink_right, a, c), jnp.where(shrink_right, d, b)
 
-    a, b, c, d, fc, fd = jax.lax.fori_loop(0, iters, body, (a, b, c, d, fc, fd))
+    a, b = jax.lax.fori_loop(0, iters, body, (a, b))
     return 0.5 * (a + b)
 
 

@@ -181,3 +181,80 @@ def test_bracket_warm_start_value_identical(fleet):
                                             prior_log_hi=hi_cold)
     assert float(hi_warm2) == float(hi_starved)
     assert_identical(warm2, starved)
+
+
+def _staged_price_search(total_at, cap, hi_start=None, iters=60,
+                         endpoint="mid"):
+    """Reference for ``price_search``: its stages written out one after
+    another in numpy (need, warm snap + contraction, expansion,
+    bisection), one evaluation of ``total_at`` per step."""
+    from repro.core.resource import (_LOG_PRICE_HI0, _LOG_PRICE_HI_MAX,
+                                     _LOG_PRICE_LO, _LOG_PRICE_STEP)
+
+    excess = lambda x: total_at(10.0**x) - cap
+    need = total_at(0.0) > cap
+    hi0 = _LOG_PRICE_HI0
+    if hi_start is None:
+        hi = hi0
+        f_hi = excess(hi)
+    else:
+        k = np.round((hi_start - hi0) / _LOG_PRICE_STEP)
+        k_max = (_LOG_PRICE_HI_MAX - _LOG_PRICE_HI0) // _LOG_PRICE_STEP
+        hi = hi0 + np.clip(k, 0.0, k_max) * _LOG_PRICE_STEP
+        f_hi = excess(hi)
+        while hi > hi0 + 1e-9:
+            f_dn = excess(hi - _LOG_PRICE_STEP)
+            if f_dn > 0.0:
+                break
+            hi, f_hi = hi - _LOG_PRICE_STEP, f_dn
+    while f_hi > 0.0 and hi < _LOG_PRICE_HI_MAX - 1e-9:
+        hi += _LOG_PRICE_STEP
+        f_hi = excess(hi)
+    lo, b_hi = _LOG_PRICE_LO, hi
+    f_lo = excess(lo)
+    for _ in range(iters):
+        mid = 0.5 * (lo + b_hi)
+        f_mid = excess(mid)
+        if np.sign(f_mid) == np.sign(f_lo):
+            lo, f_lo = mid, f_mid
+        else:
+            b_hi = mid
+    log_p = b_hi if endpoint == "hi" else 0.5 * (lo + b_hi)
+    return log_p, need, hi
+
+
+@pytest.mark.parametrize("endpoint", ["mid", "hi"])
+@pytest.mark.parametrize("hi_start", [None, 2.0, 9.0, 18.0])
+@pytest.mark.parametrize("cap", [0.5, 3.0, 1e-9, 50.0])
+def test_price_search_matches_staged_reference(cap, hi_start, endpoint):
+    """``price_search`` runs need → bracket → bisection → final solve in
+    one loop with one evaluation site; it must land where the stages
+    written out one by one land (a priced demand 1 + 1e6/(1 + p) falls
+    with the price p; cap 1e-9 cannot be cleared even at the top price,
+    cap 50 needs no price)."""
+    from repro.core.resource import price_search
+
+    demand = lambda p: 1.0 + 1e6 / (1.0 + p)
+    out, log_p, need, log_hi = price_search(
+        lambda p: (demand(p), 2.0 * p), lambda o: o[0], cap,
+        hi_start=hi_start, endpoint=endpoint)
+    want_p, want_need, want_hi = _staged_price_search(
+        demand, cap, hi_start=hi_start, endpoint=endpoint)
+    assert bool(need) == bool(want_need)
+    assert float(log_hi) == want_hi
+    np.testing.assert_allclose(float(log_p), want_p, rtol=1e-12)
+    price = 10.0**float(log_p) if want_need else 0.0
+    np.testing.assert_allclose(np.asarray(out[1]), 2.0 * price, rtol=1e-12)
+    _, _, _, cold_hi = price_search(
+        lambda p: (demand(p), p), lambda o: o[0], cap, endpoint=endpoint)
+    assert float(log_hi) == float(cold_hi)  # warm start is value-identical
+
+
+def test_price_search_without_final_solve():
+    from repro.core.resource import price_search
+
+    out, log_p, need, _ = price_search(lambda p: 4.0 / (1.0 + p),
+                                       lambda o: o, 1.0, endpoint="hi",
+                                       final=False)
+    assert out is None and bool(need)
+    assert 4.0 / (1.0 + 10.0**float(log_p)) <= 1.0  # upper end clears
