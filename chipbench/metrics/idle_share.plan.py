@@ -1,0 +1,7 @@
+"""Share of the traced window in which no operation ran on the device, %."""
+
+
+def read(s):
+    if s.window_s <= 0 or not s.devices:
+        return None
+    return 100.0 * (1.0 - s.busy_s / s.window_s)
