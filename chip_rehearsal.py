@@ -146,6 +146,7 @@ def main(argv=None):
     from jax.sharding import Mesh, SingleDeviceSharding
 
     import chip_smoke
+    from chipbench.run import CompileClock
 
     if jax.devices()[0].platform != "cpu":
         print("chip_rehearsal: run with JAX_PLATFORMS=cpu", file=sys.stderr)
@@ -156,7 +157,7 @@ def main(argv=None):
     jax.config.update("jax_enable_compilation_cache", False)
     rec = Recorder()
     rec.install()
-    clock = chip_smoke.CompileClock()
+    clock = CompileClock()
 
     t0 = time.perf_counter()
     devices = jax.devices()

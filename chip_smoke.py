@@ -40,6 +40,8 @@ import warnings
 
 import numpy as np
 
+from chipbench.run import CompileClock
+
 ROOT = os.path.dirname(os.path.abspath(__file__))
 
 #: golden settings of tests/golden/seed_plans.json (tests/test_plan_golden.py)
@@ -72,25 +74,6 @@ def _setup():
     # threefry bit layout; the partitionable default draws other fleets.
     jax.config.update("jax_threefry_partitionable", False)
     warnings.filterwarnings("error", message="plan fail-soft")
-
-
-class CompileClock:
-    """Backend compile seconds and persistent-cache hits so far."""
-
-    def __init__(self):
-        import jax
-
-        self.secs, self.hits = 0.0, 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.secs += duration
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
 
 
 def _devices_of(tree, devices, committed=True):
@@ -323,11 +306,12 @@ class Steps:
         self.wall, self.compile_s, self.hits = {}, {}, {}
 
     def run(self, name, thunk):
-        c0, h0, t0 = self.clock.secs, self.clock.hits, time.perf_counter()
+        c0, h0 = self.clock.secs["backend_s"], self.clock.hits
+        t0 = time.perf_counter()
         print(f"[{name}] start, host rss {_rss_gib():.1f} GiB", flush=True)
         out = thunk()
         self.wall[name] = time.perf_counter() - t0
-        self.compile_s[name] = self.clock.secs - c0
+        self.compile_s[name] = self.clock.secs["backend_s"] - c0
         self.hits[name] = self.clock.hits - h0
         print(f"[{name}] done in {self.wall[name]:.1f} s, host rss "
               f"{_rss_gib():.1f} GiB", flush=True)
@@ -455,7 +439,7 @@ def main(argv=None):
         run_one_chip(devices[0], clock)
     print(json.dumps({
         "total_wall_s": time.perf_counter() - t0,
-        "total_compile_s": clock.secs,
+        "total_compile_s": clock.secs["backend_s"],
         "cache_hits": clock.hits,
         "host_peak_rss_gib":
             resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 2**20}),

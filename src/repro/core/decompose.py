@@ -58,6 +58,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -96,6 +97,19 @@ from repro.parallel.sharding import planner_mesh
 
 __all__ = ["ShardedGroup", "build_groups", "bucket_size", "plan_sharded",
            "program_cache_sizes"]
+
+#: Host spans of the sharded planner (``jax.profiler.TraceAnnotation``):
+#: a profiler trace holds them on the device's clock, and with no profiler
+#: session each costs about a microsecond. A probe is one host round trip
+#: of a price clearing; its wait is the host blocked on the device's
+#: partial sum and its copy back.
+_SPAN_PLAN = "repro.plan_sharded"
+_SPAN_GROUPS = "repro.build_groups"
+_SPAN_STEP = "repro.step"
+_SPAN_LAM = "repro.price.lam"
+_SPAN_MU = "repro.price.mu"
+_SPAN_PROBE = "repro.price.probe"
+_SPAN_WAIT = "repro.price.wait"
 
 
 # ---------------------------------------------------------------------------
@@ -147,26 +161,28 @@ def build_groups(spec: FleetSpec, gains, mesh) -> list:  # analyze: ok(TRC002): 
     the fleet-order ``(N,)`` gains vector (``FleetSpec.sample_gains`` —
     the same sequence ``spec.build(key)`` would bake into the monolithic
     fleet, which is what makes the two paths comparable at a key)."""
-    gains = np.asarray(jnp.asarray(gains, jnp.float64))
-    if gains.shape != (spec.num_devices,):
-        raise ValueError(
-            f"gains must be ({spec.num_devices},) for this spec, "
-            f"got shape {gains.shape}")
-    mesh_size = int(mesh.devices.size)
-    groups = []
-    for g, (start, stop) in zip(spec.groups, spec.group_slices(), strict=True):
-        n = g.count
-        n_pad = bucket_size(n, mesh_size)
-        gg = np.concatenate(
-            [gains[start:stop], np.repeat(gains[stop - 1:stop], n_pad - n)])
-        sub = FleetSpec((replace(g, count=n_pad),), area_m=spec.area_m,
-                        min_dist_m=spec.min_dist_m)
-        w = np.zeros(n_pad)
-        w[:n] = 1.0
-        groups.append(ShardedGroup(
-            fleet=sub.build(gains=jnp.asarray(gg)), n=n, n_pad=n_pad,
-            start=start, stop=stop, name=g.name, w=jnp.asarray(w)))
-    return groups
+    with TraceAnnotation(_SPAN_GROUPS):
+        gains = np.asarray(jnp.asarray(gains, jnp.float64))
+        if gains.shape != (spec.num_devices,):
+            raise ValueError(
+                f"gains must be ({spec.num_devices},) for this spec, "
+                f"got shape {gains.shape}")
+        mesh_size = int(mesh.devices.size)
+        groups = []
+        for g, (start, stop) in zip(spec.groups, spec.group_slices(),
+                                    strict=True):
+            n = g.count
+            n_pad = bucket_size(n, mesh_size)
+            gg = np.concatenate([gains[start:stop],
+                                 np.repeat(gains[stop - 1:stop], n_pad - n)])
+            sub = FleetSpec((replace(g, count=n_pad),), area_m=spec.area_m,
+                            min_dist_m=spec.min_dist_m)
+            w = np.zeros(n_pad)
+            w[:n] = 1.0
+            groups.append(ShardedGroup(
+                fleet=sub.build(gains=jnp.asarray(gg)), n=n, n_pad=n_pad,
+                start=start, stop=stop, name=g.name, w=jnp.asarray(w)))
+        return groups
 
 
 def _pad_lanes(a: np.ndarray, n_pad: int) -> np.ndarray:  # analyze: ok(TRC002): host-side numpy padding of concrete scenario slices
@@ -552,21 +568,25 @@ def _lam_clear(programs, groups, preps, B_dev, B_host, S, lam_hi):  # analyze: o
     """
 
     def excess(log_lam, need):
-        ll, nd = jnp.asarray(log_lam, jnp.float64), jnp.asarray(need)
-        tot = None
-        for g, p in zip(groups, preps, strict=True):
-            part = programs.bsum(p, g.w, B_dev, ll, nd)
-            tot = part if tot is None else tot + part
-        return np.asarray(tot) - B_host
+        with TraceAnnotation(_SPAN_PROBE):
+            ll, nd = jnp.asarray(log_lam, jnp.float64), jnp.asarray(need)
+            tot = None
+            for g, p in zip(groups, preps, strict=True):
+                part = programs.bsum(p, g.w, B_dev, ll, nd)
+                tot = part if tot is None else tot + part
+            with TraceAnnotation(_SPAN_WAIT):
+                tot = np.asarray(tot)
+            return tot - B_host
 
-    all_on = np.ones(S, bool)
-    need = excess(np.zeros(S), np.zeros(S, bool)) > 0.0
-    if not need.any():
-        return np.zeros(S), need, lam_hi
-    fn = lambda x: excess(x, all_on)
-    hi, _ = _host_expand(fn, hi_start=lam_hi)
-    log_lam = _host_bisect(fn, np.full(S, _LOG_PRICE_LO), hi, iters=60)
-    return log_lam, need, hi
+    with TraceAnnotation(_SPAN_LAM):
+        all_on = np.ones(S, bool)
+        need = excess(np.zeros(S), np.zeros(S, bool)) > 0.0
+        if not need.any():
+            return np.zeros(S), need, lam_hi
+        fn = lambda x: excess(x, all_on)
+        hi, _ = _host_expand(fn, hi_start=lam_hi)
+        log_lam = _host_bisect(fn, np.full(S, _LOG_PRICE_LO), hi, iters=60)
+        return log_lam, need, hi
 
 
 def _mu_clear(programs, groups, states, cap_host, S, mu_hi):  # analyze: ok(TRC001,TRC002,TRC003): host-level global price loop by design
@@ -577,22 +597,26 @@ def _mu_clear(programs, groups, states, cap_host, S, mu_hi):  # analyze: ok(TRC0
     ``planner._clearing_price``."""
 
     def occ_excess(log_mu, need):
-        lm, nd = jnp.asarray(log_mu, jnp.float64), jnp.asarray(need)
-        tot = None
-        for g, st in zip(groups, states, strict=True):
-            part = programs.occ_sum(g.fleet.chain.t_vm, *st, g.w, lm, nd)
-            tot = part if tot is None else tot + part
-        return np.asarray(tot) - cap_host
+        with TraceAnnotation(_SPAN_PROBE):
+            lm, nd = jnp.asarray(log_mu, jnp.float64), jnp.asarray(need)
+            tot = None
+            for g, st in zip(groups, states, strict=True):
+                part = programs.occ_sum(g.fleet.chain.t_vm, *st, g.w, lm, nd)
+                tot = part if tot is None else tot + part
+            with TraceAnnotation(_SPAN_WAIT):
+                tot = np.asarray(tot)
+            return tot - cap_host
 
-    all_on = np.ones(S, bool)
-    need = occ_excess(np.zeros(S), np.zeros(S, bool)) > 0.0
-    if not need.any():
-        return np.zeros(S), need, mu_hi
-    fn = lambda x: occ_excess(x, all_on)
-    hi, _ = _host_expand(fn, hi_start=mu_hi)
-    log_mu = _host_bisect(fn, np.full(S, _LOG_PRICE_LO), hi, iters=60,
-                          endpoint="hi")
-    return log_mu, need, hi
+    with TraceAnnotation(_SPAN_MU):
+        all_on = np.ones(S, bool)
+        need = occ_excess(np.zeros(S), np.zeros(S, bool)) > 0.0
+        if not need.any():
+            return np.zeros(S), need, mu_hi
+        fn = lambda x: occ_excess(x, all_on)
+        hi, _ = _host_expand(fn, hi_start=mu_hi)
+        log_mu = _host_bisect(fn, np.full(S, _LOG_PRICE_LO), hi, iters=60,
+                              endpoint="hi")
+        return log_mu, need, hi
 
 
 def _mu_clear_nodes(programs, groups, states, masks, caps_host, S, mu_hi):  # analyze: ok(TRC001,TRC002,TRC003): host-level global price loop by design
@@ -611,21 +635,25 @@ def _mu_clear_nodes(programs, groups, states, masks, caps_host, S, mu_hi):  # an
     all_on = np.ones(S, bool)
     for e in range(e_count):
         def occ_excess(lm_s, need_s, e=e):
-            ll, nd = jnp.asarray(lm_s, jnp.float64), jnp.asarray(need_s)
-            tot = None
-            for g, st, mk in zip(groups, states, masks, strict=True):
-                part = programs.occ_sum_node(g.fleet.chain.t_vm, mk[e], *st,
-                                             g.w, ll, nd)
-                tot = part if tot is None else tot + part
-            return np.asarray(tot) - caps_host[e]
+            with TraceAnnotation(_SPAN_PROBE):
+                ll, nd = jnp.asarray(lm_s, jnp.float64), jnp.asarray(need_s)
+                tot = None
+                for g, st, mk in zip(groups, states, masks, strict=True):
+                    part = programs.occ_sum_node(g.fleet.chain.t_vm, mk[e],
+                                                 *st, g.w, ll, nd)
+                    tot = part if tot is None else tot + part
+                with TraceAnnotation(_SPAN_WAIT):
+                    tot = np.asarray(tot)
+                return tot - caps_host[e]
 
-        need_e = occ_excess(np.zeros(S), np.zeros(S, bool)) > 0.0
-        if not need_e.any():
-            continue
-        fn = lambda x: occ_excess(x, all_on)
-        hi, _ = _host_expand(fn, hi_start=mu_hi[e])
-        log_mu[e] = _host_bisect(fn, np.full(S, _LOG_PRICE_LO), hi, iters=60,
-                                 endpoint="hi")
+        with TraceAnnotation(_SPAN_MU):
+            need_e = occ_excess(np.zeros(S), np.zeros(S, bool)) > 0.0
+            if not need_e.any():
+                continue
+            fn = lambda x: occ_excess(x, all_on)
+            hi, _ = _host_expand(fn, hi_start=mu_hi[e])
+            log_mu[e] = _host_bisect(fn, np.full(S, _LOG_PRICE_LO), hi,
+                                     iters=60, endpoint="hi")
         mu_need[e] = need_e
         hi_out[e] = hi
     return log_mu, mu_need, hi_out
@@ -739,83 +767,88 @@ def _plan_groups(groups, sc, policy: Policy, outer_iters, m0_groups, S,  # analy
         return preps, sols, log_lam, need, hi
 
     for _ in range(outer_iters):
+        with TraceAnnotation(_SPAN_STEP):
+            preps, sols, log_lam, lam_need, lam_hi = lam_solve(m_gs)
+            nd = jnp.asarray(lam_need)
+            b_cat = _global_rescale(
+                _cat_real([s[0] for s in sols], groups),
+                _cat_real([p.b_lo for p in preps], groups), nd, B_dev)
+            b_gs = [_repad(b_cat[:, g.start:g.stop], g.n_pad)
+                    for g in groups]
+            f_gs = [s[1] for s in sols]
+            if multi_node:
+                a_now = host_assignment(m_gs)
+                if price_edge:
+                    states = [programs.edge_state(g.fleet, b, f, dl, ep)
+                              for g, b, f, dl, ep in zip(
+                                  groups, b_gs, f_gs, dls, epss, strict=True)]
+                    log_mu_e, mu_need_e, mu_hi_e = _mu_clear_nodes(
+                        programs, groups, states, node_masks(a_now),
+                        caps_host, S, mu_hi_e)
+                lms, nds = per_device_prices(a_now, log_mu_e, mu_need_e)
+                parts = [programs.partition_nodes(g.fleet, m, b, f, lmd, ndd,
+                                                  dl, ep, g.w)
+                         for g, m, b, f, lmd, ndd, dl, ep in zip(
+                             groups, m_gs, b_gs, f_gs, lms, nds, dls, epss,
+                             strict=True)]
+            else:
+                if price_edge:
+                    states = [programs.edge_state(g.fleet, b, f, dl, ep)
+                              for g, b, f, dl, ep in zip(
+                                  groups, b_gs, f_gs, dls, epss, strict=True)]
+                    log_mu, mu_need, mu_hi = _mu_clear(
+                        programs, groups, states, cap_host, S, mu_hi)
+                lm, mn = jnp.asarray(log_mu), jnp.asarray(mu_need)
+                parts = [programs.partition(g.fleet, m, b, f, lm, mn, dl, ep,
+                                            g.w)
+                         for g, m, b, f, dl, ep in zip(
+                             groups, m_gs, b_gs, f_gs, dls, epss, strict=True)]
+            m_gs = [pt[0] for pt in parts]
+            part_feas = _cat_real([pt[1] for pt in parts], groups)
+            iters_steps.append(_cat_real([pt[2] for pt in parts], groups))
+            objs.append(sum(np.asarray(pt[3]) for pt in parts))
+
+    with TraceAnnotation(_SPAN_STEP):
         preps, sols, log_lam, lam_need, lam_hi = lam_solve(m_gs)
-        nd = jnp.asarray(lam_need)
-        b_cat = _global_rescale(
-            _cat_real([s[0] for s in sols], groups),
-            _cat_real([p.b_lo for p in preps], groups), nd, B_dev)
-        b_gs = [_repad(b_cat[:, g.start:g.stop], g.n_pad) for g in groups]
-        f_gs = [s[1] for s in sols]
+        prep_cat = jax.tree_util.tree_map(
+            lambda *xs: _cat_real(xs, groups), *preps)
+        b_cat = _cat_real([s[0] for s in sols], groups)
+        f_cat = _cat_real([s[1] for s in sols], groups)
+        feas_cat = _cat_real([s[2] for s in sols], groups)
         if multi_node:
-            a_now = host_assignment(m_gs)
-            if price_edge:
-                states = [programs.edge_state(g.fleet, b, f, dl, ep)
-                          for g, b, f, dl, ep in zip(groups, b_gs, f_gs, dls,
-                                                     epss, strict=True)]
-                log_mu_e, mu_need_e, mu_hi_e = _mu_clear_nodes(
-                    programs, groups, states, node_masks(a_now), caps_host,
-                    S, mu_hi_e)
-            lms, nds = per_device_prices(a_now, log_mu_e, mu_need_e)
-            parts = [programs.partition_nodes(g.fleet, m, b, f, lmd, ndd,
-                                              dl, ep, g.w)
-                     for g, m, b, f, lmd, ndd, dl, ep in zip(
-                         groups, m_gs, b_gs, f_gs, lms, nds, dls, epss,
-                         strict=True)]
+            # like the monolithic tail: assignment recomputed at the final m,
+            # priced with the last step's node prices
+            assignment_s = jnp.asarray(host_assignment(m_gs))
+            (alloc_s, total_s, feas_s, margins_s,
+             status_s) = _global_finish_nodes(
+                prep_cat, b_cat, f_cat, feas_cat, part_feas, B_dev,
+                jnp.asarray(log_lam), jnp.asarray(lam_need), cap_dev,
+                jnp.asarray(log_mu_e.T), jnp.asarray(mu_need_e.T),
+                assignment_s, sc.deadline, sc.eps,
+                sigma_model=policy.sigma_model, channel_cv=channel_cv)
         else:
-            if price_edge:
-                states = [programs.edge_state(g.fleet, b, f, dl, ep)
-                          for g, b, f, dl, ep in zip(groups, b_gs, f_gs, dls,
-                                                     epss, strict=True)]
-                log_mu, mu_need, mu_hi = _mu_clear(programs, groups, states,
-                                                   cap_host, S, mu_hi)
-            lm, mn = jnp.asarray(log_mu), jnp.asarray(mu_need)
-            parts = [programs.partition(g.fleet, m, b, f, lm, mn, dl, ep, g.w)
-                     for g, m, b, f, dl, ep in zip(groups, m_gs, b_gs, f_gs,
-                                                   dls, epss, strict=True)]
-        m_gs = [pt[0] for pt in parts]
-        part_feas = _cat_real([pt[1] for pt in parts], groups)
-        iters_steps.append(_cat_real([pt[2] for pt in parts], groups))
-        objs.append(sum(np.asarray(pt[3]) for pt in parts))
+            assignment_s = jnp.zeros(
+                (S, int(b_cat.shape[1])), jnp.int32)
+            alloc_s, total_s, feas_s, margins_s, status_s = _global_finish(
+                prep_cat, b_cat, f_cat, feas_cat, part_feas, B_dev,
+                jnp.asarray(log_lam), jnp.asarray(lam_need), cap_dev,
+                jnp.asarray(log_mu), jnp.asarray(mu_need), sc.deadline,
+                sc.eps, sigma_model=policy.sigma_model, channel_cv=channel_cv)
 
-    preps, sols, log_lam, lam_need, lam_hi = lam_solve(m_gs)
-    prep_cat = jax.tree_util.tree_map(
-        lambda *xs: _cat_real(xs, groups), *preps)
-    b_cat = _cat_real([s[0] for s in sols], groups)
-    f_cat = _cat_real([s[1] for s in sols], groups)
-    feas_cat = _cat_real([s[2] for s in sols], groups)
-    if multi_node:
-        # like the monolithic tail: assignment recomputed at the final m,
-        # priced with the last step's node prices
-        assignment_s = jnp.asarray(host_assignment(m_gs))
-        alloc_s, total_s, feas_s, margins_s, status_s = _global_finish_nodes(
-            prep_cat, b_cat, f_cat, feas_cat, part_feas, B_dev,
-            jnp.asarray(log_lam), jnp.asarray(lam_need), cap_dev,
-            jnp.asarray(log_mu_e.T), jnp.asarray(mu_need_e.T), assignment_s,
-            sc.deadline, sc.eps, sigma_model=policy.sigma_model,
-            channel_cv=channel_cv)
-    else:
-        assignment_s = jnp.zeros(
-            (S, int(b_cat.shape[1])), jnp.int32)
-        alloc_s, total_s, feas_s, margins_s, status_s = _global_finish(
-            prep_cat, b_cat, f_cat, feas_cat, part_feas, B_dev,
-            jnp.asarray(log_lam), jnp.asarray(lam_need), cap_dev,
-            jnp.asarray(log_mu), jnp.asarray(mu_need), sc.deadline, sc.eps,
-            sigma_model=policy.sigma_model, channel_cv=channel_cv)
-
-    plans = Plan(
-        m_sel=_cat_real(m_gs, groups),
-        alloc=alloc_s,
-        total_energy=total_s,
-        feasible=feas_s,
-        objective_trace=jnp.swapaxes(
-            jnp.asarray(np.stack(objs, axis=0)), 0, 1),  # (S, outer)
-        pccp_iters=jnp.stack(iters_steps, axis=1),  # (S, outer, N)
-        margins=margins_s,
-        status=status_s,
-        assignment=assignment_s,
-    )
-    idx = int(_select_best(plans))
-    return jax.tree_util.tree_map(lambda x: x[idx], plans)
+        plans = Plan(
+            m_sel=_cat_real(m_gs, groups),
+            alloc=alloc_s,
+            total_energy=total_s,
+            feasible=feas_s,
+            objective_trace=jnp.swapaxes(
+                jnp.asarray(np.stack(objs, axis=0)), 0, 1),  # (S, outer)
+            pccp_iters=jnp.stack(iters_steps, axis=1),  # (S, outer, N)
+            margins=margins_s,
+            status=status_s,
+            assignment=assignment_s,
+        )
+        idx = int(_select_best(plans))
+        return jax.tree_util.tree_map(lambda x: x[idx], plans)
 
 
 # ---------------------------------------------------------------------------
@@ -1067,37 +1100,38 @@ def plan_sharded(spec: FleetSpec, scenario, config, *, key=None, gains=None,  # 
     path), and there is no host fail-soft ladder — ``Plan.status`` still
     carries the traced OK/DEGRADED stamp for the caller to act on.
     """
-    policy = get_policy(config.policy)
-    if getattr(config, "edge_eps", None) is not None:
-        raise NotImplementedError(
-            "plan_sharded does not support the Cantelli edge_eps occupancy "
-            "row yet — plan monolithically (Planner.plan) for "
-            "chance-constrained edge capacity")
-    if mesh is None:
-        mesh = planner_mesh()
-    if gains is None:
-        if key is None:
-            raise ValueError("plan_sharded needs a PRNG key (to place "
-                             "devices) or explicit link gains")
-        gains = spec.sample_gains(key)
-    sc = scenario.normalized(spec.num_devices)
-    groups = build_groups(spec, gains, mesh)
+    with TraceAnnotation(_SPAN_PLAN):
+        policy = get_policy(config.policy)
+        if getattr(config, "edge_eps", None) is not None:
+            raise NotImplementedError(
+                "plan_sharded does not support the Cantelli edge_eps "
+                "occupancy row yet — plan monolithically (Planner.plan) for "
+                "chance-constrained edge capacity")
+        if mesh is None:
+            mesh = planner_mesh()
+        if gains is None:
+            if key is None:
+                raise ValueError("plan_sharded needs a PRNG key (to place "
+                                 "devices) or explicit link gains")
+            gains = spec.sample_gains(key)
+        sc = scenario.normalized(spec.num_devices)
+        groups = build_groups(spec, gains, mesh)
 
-    if policy.solve is not None:
-        if init_m is not None or config.init_m is not None:
-            raise ValueError(
-                f"policy {policy.name!r} solves exactly (no alternation), "
-                "so init_m warm starts have no effect — drop init_m or pick "
-                "an alternating policy")
-        return _plan_optimal_sharded(groups, sc, policy, mesh)
+        if policy.solve is not None:
+            if init_m is not None or config.init_m is not None:
+                raise ValueError(
+                    f"policy {policy.name!r} solves exactly (no "
+                    "alternation), so init_m warm starts have no effect — "
+                    "drop init_m or pick an alternating policy")
+            return _plan_optimal_sharded(groups, sc, policy, mesh)
 
-    if init_m is None:
-        init_m = config.init_m
-    m0_groups = _resolve_starts(spec, init_m, config.multi_start)
-    S = int(m0_groups[0].shape[0])
-    programs = _group_programs(
-        mesh, policy, int(config.pccp_iters), str(config.solver),
-        bool(config.pccp_gated), float(config.channel_cv))
-    return _plan_groups(groups, sc, policy, int(config.outer_iters),
-                        m0_groups, S, programs, float(config.channel_cv),
-                        mesh)
+        if init_m is None:
+            init_m = config.init_m
+        m0_groups = _resolve_starts(spec, init_m, config.multi_start)
+        S = int(m0_groups[0].shape[0])
+        programs = _group_programs(
+            mesh, policy, int(config.pccp_iters), str(config.solver),
+            bool(config.pccp_gated), float(config.channel_cv))
+        return _plan_groups(groups, sc, policy, int(config.outer_iters),
+                            m0_groups, S, programs, float(config.channel_cv),
+                            mesh)
