@@ -9,7 +9,7 @@ Two solvers:
 - ``allocate`` (primary): Lagrangian dual on the single coupling
   constraint Σ b_n ≤ B. For a bandwidth price λ the problem separates per
   device; the inner 1-D problem over b is convex (partial minimization
-  over f is closed-form), solved by grid+golden section; λ is found by
+  over f is closed-form), solved by golden section; λ is found by
   bisection on Σ b*(λ) − B. Strong duality holds (convex + Slater), so
   this matches the paper's interior-point optimum.
 - ``allocate_ipm`` (cross-check): the paper-faithful joint interior-point

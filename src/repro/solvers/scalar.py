@@ -5,7 +5,6 @@ jitted, vmapped and nested inside other solvers without dynamic shapes.
 """
 from __future__ import annotations
 
-from functools import partial
 from typing import Callable
 
 import jax
@@ -74,44 +73,42 @@ def bisect(fn: Callable, lo, hi, iters: int = 80, endpoint: str = "mid"):
 def golden_section(fn: Callable, lo, hi, iters: int = 72):
     """Minimize a (quasi-)convex scalar ``fn`` on [lo, hi].
 
-    Returns the argmin. 72 iterations shrink the bracket by
-    ~phi^-72 ≈ 1e-15, i.e. to float64 resolution for O(1) intervals.
+    Returns the midpoint of the final bracket. ``iters`` shrinks, each
+    by 1/phi, take the bracket to ~phi^-72 ≈ 1e-15 of its width, i.e. to
+    float64 resolution for O(1) intervals.
+
+    The classic scheme: the interior point kept by a shrink is the other
+    point of the new bracket, so each shrink evaluates ``fn`` once, and
+    the carry holds both interior points and their values. Steps 0 and 1
+    evaluate the first two points, steps 2..iters+1 shrink, so ``fn`` is
+    evaluated ``iters + 2`` times at one call site, which keeps the
+    program to a single copy of ``fn``.
     """
     lo = jnp.asarray(lo, dtype=jnp.float64)
     hi = jnp.asarray(hi, dtype=jnp.float64)
     f_type = jax.eval_shape(fn, lo)
-    a, b = _match_vma(lo, hi, f_type), _match_vma(hi, lo, f_type)
+    f0 = jnp.zeros(f_type.shape, f_type.dtype)
+    h = hi - lo
+    a, b, c, d, f_c, f_d = (_match_vma(x, lo, hi, f_type) for x in (
+        lo, hi, lo + _INV_PHI2 * h, lo + _INV_PHI * h, f0, f0))
 
-    def body(_, state):
-        a, b = state
+    def body(i, state):
+        a, b, c, d, f_c, f_d = state
+        shrink = i >= 2
+        # Keep [a, d] (c becomes the new d) or [c, b] (d becomes the new
+        # c); the new point sits at the golden cut of the new bracket.
+        c_wins = f_c < f_d
+        left, right = shrink & c_wins, shrink & ~c_wins
+        a = jnp.where(right, c, a)
+        b = jnp.where(left, d, b)
         h = b - a
-        c = a + _INV_PHI2 * h
-        d = a + _INV_PHI * h
-        # Only one of (c, d) needs re-evaluation per iteration in the
-        # classic scheme; evaluating both keeps the state static-shaped,
-        # and one vmapped call of ``fn`` on the pair keeps the program to
-        # a single copy of ``fn``.
-        fc, fd = jax.vmap(fn)(jnp.stack([c, d]))
-        shrink_right = fc < fd
-        return jnp.where(shrink_right, a, c), jnp.where(shrink_right, d, b)
+        c, d = (jnp.where(left, a + _INV_PHI2 * h, jnp.where(right, d, c)),
+                jnp.where(left, c, jnp.where(right, a + _INV_PHI * h, d)))
+        f_c, f_d = jnp.where(right, f_d, f_c), jnp.where(left, f_c, f_d)
+        at_c = (i == 0) | left
+        f_x = fn(jnp.where(at_c, c, d))
+        return a, b, c, d, jnp.where(at_c, f_x, f_c), jnp.where(at_c, f_d, f_x)
 
-    a, b = jax.lax.fori_loop(0, iters, body, (a, b))
+    a, b, *_ = jax.lax.fori_loop(0, iters + 2, body, (a, b, c, d, f_c, f_d))
     return 0.5 * (a + b)
 
-
-@partial(jax.jit, static_argnames=("fn", "grid"))
-def minimize_grid_then_golden(fn: Callable, lo, hi, grid: int = 64):
-    """Global-ish 1-D minimization: coarse grid to localize, then golden.
-
-    Useful when ``fn`` is only piecewise-convex (e.g. clipped frequency
-    requirement inside an energy expression).
-    """
-    lo = jnp.asarray(lo, dtype=jnp.float64)
-    hi = jnp.asarray(hi, dtype=jnp.float64)
-    xs = jnp.linspace(lo, hi, grid)
-    vals = jax.vmap(fn)(xs)
-    i = jnp.argmin(vals)
-    cell = (hi - lo) / (grid - 1)
-    a = jnp.clip(xs[i] - cell, lo, hi)
-    b = jnp.clip(xs[i] + cell, lo, hi)
-    return golden_section(fn, a, b)

@@ -258,3 +258,59 @@ def test_price_search_without_final_solve():
                                        final=False)
     assert out is None and bool(need)
     assert 4.0 / (1.0 + 10.0**float(log_p)) <= 1.0  # upper end clears
+
+
+def _pairwise_golden(fn, lo, hi, iters=72):
+    """Plain reference: the golden section that evaluates ``fn`` at both
+    interior points of every bracket (no point carried between steps)."""
+    from repro.solvers.scalar import _INV_PHI, _INV_PHI2
+
+    def body(_, ab):
+        a, b = ab
+        h = b - a
+        c, d = a + _INV_PHI2 * h, a + _INV_PHI * h
+        keep_left = fn(c) < fn(d)
+        return jnp.where(keep_left, a, c), jnp.where(keep_left, d, b)
+
+    a, b = jax.lax.fori_loop(0, iters, body, (lo, hi))
+    return 0.5 * (a + b)
+
+
+def _priced_cost(b, lam, budget, d, w, g, kappa, f_min, f_max, p_tx, gain):
+    """e(b) + λ·b of one device: the least clock that meets the budget at
+    b, its local energy, the offload energy and the bandwidth's price."""
+    t_off = channel.offload_time(d, b, p_tx, gain)
+    f = jnp.clip(w / (jnp.maximum(g, 1e-30)
+                      * jnp.maximum(budget - t_off, 1e-12)), f_min, f_max)
+    return (energy.expected_local_energy(kappa, w, g, f)
+            + channel.offload_energy(d, b, p_tx, gain) + lam * b)
+
+
+def test_best_bandwidth_no_costlier_than_pairwise_golden():
+    """At fleet scale and at every price, each device's b* costs no more
+    (to 1e-12) than the b* of the pairwise golden section; b itself is
+    resolved only to ~√eps, so it is not pinned."""
+    from repro.core.resource import _alloc_prep, _alloc_solve_at
+
+    n, B = 512, 10e6 / 12 * 512
+    fleet = alexnet_fleet(jax.random.PRNGKey(3), n)
+    m = jax.random.randint(jax.random.PRNGKey(4), (n,), 0, 9)
+    deadline, eps = jnp.full((n,), 0.18), jnp.full((n,), 0.02)
+    prep = _alloc_prep(fleet, m, deadline, eps, B)
+    sel = prep.sel
+    cols = (prep.budget, sel.d_bits, sel.w_flops, sel.g_eff, prep.kappa,
+            prep.f_min, prep.f_max, prep.p_tx, prep.gain)
+
+    @jax.jit
+    def costs(lam):
+        b_new = _alloc_solve_at(prep, B, lam)[0]
+        cost = jax.vmap(lambda b, *c: _priced_cost(b, lam, *c))
+        b_old = jax.vmap(lambda lo, *c: _pairwise_golden(
+            lambda b: _priced_cost(b, lam, *c), lo, B))(prep.b_lo, *cols)
+        return cost(b_new, *cols), cost(b_old, *cols)
+
+    lam_star = float(allocate(fleet, m, deadline, eps, B).lam)
+    assert lam_star > 0.0  # the budget binds, so the prices below matter
+    for lam in (0.0, 0.1 * lam_star, lam_star, 10.0 * lam_star):
+        c_new, c_old = (np.asarray(c) for c in costs(jnp.float64(lam)))
+        assert np.all(c_new <= c_old + 1e-12 * np.abs(c_old)), lam

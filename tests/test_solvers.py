@@ -1,4 +1,5 @@
 """Solver-layer unit tests (bisection, golden, LM, barrier IPM)."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -18,6 +19,48 @@ def test_bisect_root():
 def test_golden_quadratic(c):
     g = golden_section(lambda x: (x - c) ** 2, -5.0, 5.0)
     assert abs(float(g) - c) < 1e-6
+
+
+@pytest.mark.parametrize("fn, lo, hi, x_min", [
+    # asymmetric convex: exp on the right, linear on the left of the min
+    (lambda x: jnp.expm1(x - 1.3) - (x - 1.3), -5.0, 5.0, 1.3),
+    # rising on the whole bracket: the min is the floor (b_lo)
+    (lambda x: 1.0 / x + 10.0 * x, 1.0, 5.0, 1.0),
+    # falling on the whole bracket: the min is the top (B at λ = 0)
+    (lambda x: 1.0 / x, 0.5, 4.0, 4.0),
+], ids=["asymmetric", "at_lo", "at_hi"])
+def test_golden_one_evaluation_per_step(fn, lo, hi, x_min):
+    iters = 72
+    calls = []
+
+    def counted(x):
+        calls.append(x)
+        return fn(x)
+
+    # (a) iters + 2 evaluations: two for the first interior points, one
+    # per shrink (``eval_shape``'s abstract call of ``fn`` is no evaluation)
+    with jax.disable_jit():
+        g = golden_section(counted, lo, hi, iters)
+    assert sum(not isinstance(x, jax.core.Tracer) for x in calls) == iters + 2
+    # (c) the argmin to 1e-9 of the bracket, eager and compiled
+    g_jit = jax.jit(golden_section, static_argnums=(0, 3))(fn, lo, hi, iters)
+    for x in (g, g_jit):
+        assert abs(float(x) - x_min) <= 1e-9 * (hi - lo)
+    # (b) the traced program applies ``fn`` once, at width 1
+    jaxpr = jax.make_jaxpr(
+        lambda a, b: golden_section(lambda x: jnp.cosh(fn(x)), a, b, iters)
+    )(lo, hi)
+    coshes = [e for e in _eqns(jaxpr.jaxpr) if e.primitive.name == "cosh"]
+    assert len(coshes) == 1
+    assert coshes[0].invars[0].aval.shape == ()
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr``, nested jaxprs (loop bodies) included."""
+    for e in jaxpr.eqns:
+        yield e
+        for sub in jax.core.jaxprs_in_params(e.params):
+            yield from _eqns(sub)
 
 
 def test_lm_fits_inverse_frequency():
