@@ -6,13 +6,14 @@ per-device share of the uplink), the channel and the planner's
 settings. The traffic file names the entry a client calls:
 
 - ``"entry": "plan_sharded"``: ``Planner.plan_sharded`` of the whole
-  fleet on a one-device ``planner_mesh``, every device held to its
-  group's deadline and ε, the groups' shares of the uplink pooled into
-  one budget.
+  fleet on a ``planner_mesh`` of the cell's chips (its lanes split
+  across them), every device held to its group's deadline and ε, the
+  groups' shares of the uplink pooled into one budget.
 
-Request ``i`` draws a fresh fleet (link gains) from ``(seed, i)`` and
-makes one call; its plan is synced and fetched to the host as a caller
-would. Only the system under test comes from the program (``repro``).
+Request ``i`` draws a fresh fleet (link gains) from ``(seed, i)`` on the
+first chip and makes one call; its plan is synced and fetched to the
+host as a caller would. Only the system under test comes from the
+program (``repro``).
 """
 from __future__ import annotations
 
@@ -21,8 +22,6 @@ import time
 from typing import NamedTuple
 
 import numpy as np
-
-from chipbench import fleetgen
 
 ENTRIES = ("plan_sharded",)
 
@@ -66,10 +65,10 @@ def _span(name: str, on: bool):
 
 class Cell:
     """Builds the planner and the fleet spec of a config, and runs
-    requests against them. Nothing here compiles until the first
-    request."""
+    requests against them on ``devices`` (a list). Nothing here compiles
+    until the first request."""
 
-    def __init__(self, config: dict, traffic: dict, seed: int, device):
+    def __init__(self, config: dict, traffic: dict, seed: int, devices):
         from repro.configs.paper_tables import build_chain
         from repro.core import DeviceSpec, FleetSpec, Planner, PlannerConfig
         from repro.core import Scenario as PlanScenario
@@ -79,7 +78,7 @@ class Cell:
             raise ValueError(f"traffic entry {traffic['entry']!r} is not one "
                              f"of {ENTRIES}")
         self.config, self.traffic, self.seed = config, traffic, seed
-        self.device = device
+        self.devices = list(devices)
         self.scenario = scenario(config)
         groups = []
         for gr in config["groups"]:
@@ -100,14 +99,17 @@ class Cell:
             pccp_iters=int(pl["pccp_iters"]),
             multi_start=bool(pl["multi_start"])))
         self.plan_scenario = PlanScenario(*self.scenario)
-        self.mesh = planner_mesh([device])
+        self.mesh = planner_mesh(self.devices)
         self.trace_spans = False
         #: seconds of the last request's draw, plan and fetch
         self.last_split = (0.0, 0.0, 0.0)
 
     def gains(self, i: int):
-        """Request ``i``'s link gains, on the device."""
-        return fleetgen.gains_for(self.config, self.seed, i, self.device)
+        """Request ``i``'s link gains, on the first device (the planner
+        copies them to the host to split them into groups)."""
+        from chipbench import fleetgen
+
+        return fleetgen.gains_for(self.config, self.seed, i, self.devices[0])
 
     def request(self, i: int) -> Answer:
         """Draw request ``i``'s fleet, plan it, fetch the plan."""

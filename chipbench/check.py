@@ -91,7 +91,8 @@ def report(numbers: dict) -> dict:
 def check_answers(config: dict, sc, answers: dict, gains_of) -> dict:
     """The worst numbers over ``answers`` ({request index: Answer}) of
     the scenario ``sc``, each request's link gains from ``gains_of(i)``;
-    the reference plans every request at once."""
+    the reference plans every request at once, each start in a process
+    of its own."""
     keys = list(answers)
     dep = reference.deployment(config, np.stack([gains_of(i) for i in keys]))
     pl = config["planner"]

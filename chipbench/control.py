@@ -57,14 +57,14 @@ def main(argv=None) -> int:
     started = run.start(args.workload)
     if isinstance(started, int):
         return started
-    entry, device = started
+    entry, devices = started
     from chipbench.cell import Cell
 
     config = run.load_config(entry["config"])
     traffic = run.load_traffic(entry["traffic"])
     lines = []
     for j, seed in enumerate(args.seeds):
-        cell = Cell(config, traffic, seed, device)
+        cell = Cell(config, traffic, seed, devices)
         reqs = range(args.requests)
         answers = {i: cell.request(i) for i in reqs}
         gains = {i: np.asarray(cell.gains(i)) for i in reqs}

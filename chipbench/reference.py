@@ -34,6 +34,8 @@ leading axes (R, K, S, devices) of one set of arrays.
 """
 from __future__ import annotations
 
+import multiprocessing
+from concurrent.futures import ProcessPoolExecutor
 from typing import NamedTuple
 
 import numpy as np
@@ -258,10 +260,31 @@ def starts(dep: Deployment, multi_start: bool):
     return np.minimum(np.asarray(spread)[:, None], last[None, :])
 
 
+def alternate(dep: Deployment, m, deadline, eps, B, outer_iters: int):
+    """Algorithm 2 from the start points ``m`` ((R, K, S, N)): ``outer_iters``
+    rounds of allocation and partition, then the allocation at the final
+    points. Every start runs on its own; returns ``(m, b, f, energy,
+    feasible)``, each (R, K, S, N)."""
+    feas_part = np.ones(m.shape, bool)
+    for _ in range(outer_iters):
+        b, f, _, _ = allocate(dep, m, deadline, eps, B)
+        m, feas_part = partition(dep, b, f, deadline, eps)
+    b, f, energy, feas_alloc = allocate(dep, m, deadline, eps, B)
+    return m, b, f, energy, feas_part & feas_alloc
+
+
 def plan(dep: Deployment, deadlines, epss, Bs, outer_iters: int = 3,
          multi_start: bool = True) -> RefPlan:
     """The reference plans of every fleet of ``dep`` for the K scenarios:
-    ``deadlines`` and ``epss`` (K,) or per device (K, N), ``Bs`` (K,)."""
+    ``deadlines`` and ``epss`` (K,) or per device (K, N), ``Bs`` (K,).
+
+    With several starts, each start runs ``alternate`` in a process of its
+    own, spawned, and the plans are the same bits as ``alternate`` over
+    every start in one process: a start's arithmetic does not depend on
+    the others'. A spawned process imports the caller's ``__main__``
+    again, so the caller's main module must be importable: a script file
+    or a ``python -m`` module (``chipbench.run`` is), not a script read
+    from standard input."""
     dt = dep.d.dtype.type
 
     def col(x):  # (1, K, 1, 1) or per device (1, K, 1, N)
@@ -272,12 +295,17 @@ def plan(dep: Deployment, deadlines, epss, Bs, outer_iters: int = 3,
     r, k = dep.snr_b.shape[0], deadline.shape[1]
     s0 = starts(dep, multi_start)
     m = np.broadcast_to(s0, (r, k) + s0.shape)
-    feas_part = np.ones(m.shape, bool)
-    for _ in range(outer_iters):
-        b, f, _, _ = allocate(dep, m, deadline, eps, B)
-        m, feas_part = partition(dep, b, f, deadline, eps)
-    b, f, energy, feas_alloc = allocate(dep, m, deadline, eps, B)
-    feasible = feas_part & feas_alloc
+    if len(s0) > 1:
+        ctx = multiprocessing.get_context("spawn")
+        with ProcessPoolExecutor(len(s0), mp_context=ctx) as pool:
+            parts = list(pool.map(
+                alternate, *zip(*[(dep, m[:, :, j:j + 1], deadline, eps, B,
+                                   outer_iters) for j in range(len(s0))])))
+        m, b, f, energy, feasible = (np.concatenate(a, axis=2)
+                                     for a in zip(*parts))
+    else:
+        m, b, f, energy, feasible = alternate(dep, m, deadline, eps, B,
+                                              outer_iters)
     total = energy.sum(axis=-1, dtype=dt)  # (R, K, S)
     bad = (~feasible).sum(axis=-1)
     best = np.argmin(np.where(bad == bad.min(axis=-1, keepdims=True), total,

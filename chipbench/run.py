@@ -23,10 +23,17 @@ and a traffic mix, ``chipbench/traffic/<traffic>.json``. The run
 5. compares a sample of the window's plans, drawn from the seed, with the
    plain reference (``chipbench.check``).
 
+A cell runs on the first ``chips`` devices that JAX lists, the planner's
+lanes split across all of them. Per-chip readings are of the fullest
+chip (``memory_peak_bytes``), of the busiest device plane of the trace
+(``launches_per_plan``, ``plan_program_ms``), or averaged over the
+planes (``busy_s``, ``idle_share.plan``, the ``breakdown``).
+
 Earlier lines of standard output carry the set-up split, the compile
 count of the window and each request's seconds (draw, plan, fetch) with
 the process's CPU seconds, page faults, context switches and garbage
-collection over it, and the machine's idle and stolen core seconds; the
+collection over it, the machine's idle and stolen core seconds, and the
+seconds of the comparison with the host's peak resident memory; the
 last line is one JSON object:
 ``correct``, ``attempted``, ``failed``, ``metrics``, ``device`` (and
 ``breakdown`` with ``--trace 1``), then ``check``, each compared number
@@ -272,15 +279,16 @@ def check_sample(answers: dict, seed: int, size: int) -> list:
     return sorted(rng.choice(keys, size=size, replace=False).tolist())
 
 
-def _memory_peak(device) -> int:
-    stats = device.memory_stats() or {}
-    return int(stats.get("peak_bytes_in_use", 0))
+def _memory_peak(devices) -> int:
+    """Peak bytes in use on the fullest of ``devices``."""
+    return max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+               for d in devices)
 
 
 def run_cell(cell_entry: dict, seed: int, seconds: float, trace: bool,
-             device, out=sys.stdout) -> dict:
-    """One run of a cell on ``device``; returns the result object (the
-    caller has checked the device and configured JAX)."""
+             devices, out=sys.stdout) -> dict:
+    """One run of a cell on ``devices`` (a list); returns the result
+    object (the caller has checked the devices and configured JAX)."""
     from chipbench import cell as cell_mod
     from chipbench import check, trace as trace_mod
 
@@ -289,7 +297,7 @@ def run_cell(cell_entry: dict, seed: int, seconds: float, trace: bool,
     traffic = load_traffic(cell_entry["traffic"])
     readers = {m["name"]: load_metric(m["name"]) for m in cell_entry["per_layer"]}
     t_build0 = time.perf_counter()
-    cell = cell_mod.Cell(config, traffic, seed, device)
+    cell = cell_mod.Cell(config, traffic, seed, devices)
     t_warm0 = time.perf_counter()
     c0 = clock.snapshot()
     cell.request(-1 & 0x7FFFFFFF)  # warm-up: a request no window makes
@@ -316,7 +324,7 @@ def run_cell(cell_entry: dict, seed: int, seconds: float, trace: bool,
         n_trace = int(traffic["trace_requests"])
     answers, span_s, took = run_window(cell, seconds, n_trace, trace_dir)
     compiles_in_window = clock.programs - programs0
-    memory_peak = _memory_peak(device)
+    memory_peak = _memory_peak(devices)
     n = len(answers)
     failed = int(sum(int(a.status != 0) for a in answers.values()))
     secs = [r["s"] for r in took]
@@ -329,11 +337,12 @@ def run_cell(cell_entry: dict, seed: int, seconds: float, trace: bool,
 
     metrics = {}
     breakdown = None
-    device_info = {"platform": device.platform, "kind": device.device_kind,
-                   "count": int(cell_entry["chips"]),
+    device_info = {"platform": devices[0].platform,
+                   "kind": devices[0].device_kind, "count": len(devices),
                    "memory_peak_bytes": memory_peak}
     if trace:
         path = _find_xplane(trace_dir)
+        xplane_bytes = os.path.getsize(path)
         summary = trace_mod.summarize(trace_mod.read_events(path))
         shutil.rmtree(trace_dir, ignore_errors=True)
         for m in cell_entry["per_layer"]:
@@ -344,6 +353,7 @@ def run_cell(cell_entry: dict, seed: int, seconds: float, trace: bool,
         breakdown = {"device_ops": summary.device_ops,
                      "idle_gaps": summary.idle_gaps}
         print(json.dumps({"trace": {"requests": summary.requests,
+                                    "xplane_bytes": xplane_bytes,
                                     "modules": summary.modules}}),
               file=out, flush=True)
     else:
@@ -354,9 +364,15 @@ def run_cell(cell_entry: dict, seed: int, seconds: float, trace: bool,
     # the comparison, after the window: the plans are on the host, the
     # device holds nothing of the program's
     sample = check_sample(answers, seed, int(traffic["check_sample"]))
+    t_check0 = time.perf_counter()
     numbers = check.check_answers(
         config, cell.scenario, {i: answers[i] for i in sample},
         lambda i: np.asarray(cell.gains(i)))
+    print(json.dumps({"comparison": {
+        "requests": len(sample), "s": time.perf_counter() - t_check0,
+        "host_peak_rss_bytes":
+            resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024}}),
+        file=out, flush=True)
     correct = n > 0 and check.verdict(numbers)
     result = {"correct": bool(correct), "attempted": n, "failed": failed,
               "metrics": metrics, "device": device_info}
@@ -377,7 +393,8 @@ def _find_xplane(trace_dir: str) -> str:
 def start(workload: str):
     """The start of every run: the program is there, JAX sees the chips
     that the cell asks for, and JAX is configured. Returns ``(cell entry,
-    device)``, or the exit code where nothing may run."""
+    the cell's devices)``: the first ``chips`` that JAX lists, or the exit
+    code where nothing may run."""
     src = os.path.join(ROOT, "src")
     if not os.path.isdir(os.path.join(src, "repro")):
         print(f"chipbench: no program to run ({src}/repro is missing)",
@@ -388,14 +405,15 @@ def start(workload: str):
     import jax
 
     devices = jax.devices()
-    if devices[0].platform != "tpu" or len(devices) < int(cell_entry["chips"]):
-        print(f"chipbench: needs {cell_entry['chips']} TPU chip(s); JAX sees "
+    chips = int(cell_entry["chips"])
+    if devices[0].platform != "tpu" or len(devices) < chips:
+        print(f"chipbench: needs {chips} TPU chip(s); JAX sees "
               f"{len(devices)} {devices[0].platform} device(s). Nothing was run.",
               file=sys.stderr)
         return NO_CHIP
     sys.path.insert(0, src)
     configure_jax()
-    return cell_entry, devices[0]
+    return cell_entry, devices[:chips]
 
 
 def main(argv=None) -> int:
@@ -409,9 +427,9 @@ def main(argv=None) -> int:
     started = start(args.workload)
     if isinstance(started, int):
         return started
-    cell_entry, device = started
+    cell_entry, devices = started
     result = run_cell(cell_entry, args.seed, args.seconds, bool(args.trace),
-                      device)
+                      devices)
     for name, c in result["check"].items():
         print(f"check {name} {c['value']!r} limit {c['limit']!r}",
               file=sys.stderr)

@@ -181,12 +181,12 @@ def main(argv=None) -> int:
     started = run.start(args.workload)
     if isinstance(started, int):
         return started
-    cell_entry, device = started
+    cell_entry, devices = started
     from chipbench import cell as cell_mod
 
     traffic = run.load_traffic(cell_entry["traffic"])
     cell = cell_mod.Cell(run.load_config(cell_entry["config"]), traffic,
-                         args.seed, device)
+                         args.seed, devices)
     cell.request(-1 & 0x7FFFFFFF)  # warm-up, as chipbench.run makes it
     trace_dir = tempfile.mkdtemp(prefix="chipbench_spans_")
     n_trace = int(traffic["trace_requests"])
@@ -196,8 +196,9 @@ def main(argv=None) -> int:
     out = reduce(events)
     out.update(traced_requests=n_trace,
                request_s=[r["s"] for r in took],
-               device={"platform": device.platform,
-                       "kind": device.device_kind})
+               device={"platform": devices[0].platform,
+                       "kind": devices[0].device_kind,
+                       "count": len(devices)})
     print(json.dumps(out), flush=True)
     return 0
 

@@ -80,7 +80,7 @@ def test_broken_timed_path_is_not_correct(monkeypatch, fault):
         monkeypatch.setattr(
             Planner, "plan_sharded",
             lambda self, *a, **k: FAULTS[fault](produce(self, *a, **k)))
-    result = run.run_cell(ENTRY, 3000000053, 0.5, False, jax.devices()[0],
+    result = run.run_cell(ENTRY, 3000000053, 0.5, False, [jax.devices()[0]],
                           out=io.StringIO())
     assert result["attempted"] >= 1
     assert result["correct"] is (fault == "none"), result["check"]

@@ -37,7 +37,7 @@ def test_config_tables_are_the_papers(config, group, fleet_fn):
     one = copy.deepcopy(config)
     one["groups"] = [g for g in one["groups"] if g["name"] == group]
     one["groups"][0]["count"] = 12
-    cell = Cell(one, run.load_traffic("sharded"), 0, jax.devices()[0])
+    cell = Cell(one, run.load_traffic("sharded"), 0, [jax.devices()[0]])
     ours = cell.spec.build(gains=np.ones(12))
     paper = getattr(pt, fleet_fn)(jax.random.PRNGKey(0), 12)
     for a, b in zip(jax.tree_util.tree_leaves(ours.chain),
@@ -80,7 +80,7 @@ def test_seed0_request0_plans_the_golden_plan(config):
     with open(GOLDEN) as f:
         golden = json.load(f)["alexnet/robust_exact"]
     cell = Cell(_alexnet12(config), run.load_traffic("sharded"), 0,
-                jax.devices()[0])
+                [jax.devices()[0]])
     ans = cell.request(0)
     assert ans.m.tolist() == golden["m_sel"]
     assert ans.feasible.astype(int).tolist() == golden["feasible"]

@@ -9,7 +9,10 @@ From the trace this module takes, inside that window:
 - the device busy intervals: the union of the program executions on
   each device plane (``XLA Modules``; every device op runs inside one),
   busy seconds averaged over the device planes;
-- the device seconds and the number of executions of each XLA module;
+- the device seconds and the number of executions of each XLA module,
+  on each device plane; the program's per request are those of the
+  busiest plane, the chip that sets the pace (``program_modules``), and
+  the breakdown's are averaged over the planes;
 - the idle gaps between busy intervals, each labelled by the host span
   in which its midpoint fell (``host`` where none did), summed by label.
 
@@ -45,10 +48,11 @@ class Summary(NamedTuple):
     window_s: float
     busy_s: float  # averaged over the device planes
     devices: int
-    modules: dict  # module name -> [executions, device seconds]
-    device_ops: list  # [[module name, seconds]], most time first
-    idle_gaps: list  # [[host span, seconds]], most idle time first
+    modules: dict  # module name -> [executions, device seconds], all planes
+    device_ops: list  # [[module name, seconds a plane]], most time first
+    idle_gaps: list  # [[host span, seconds a plane]], most idle time first
     requests: int  # chipbench.plan spans that began inside the window
+    plane_modules: dict  # device plane -> {module name: [executions, s]}
 
 
 def read_events(path: str) -> list[Event]:
@@ -99,12 +103,15 @@ def summarize(events) -> Summary:
     planes = sorted({e.plane for e in dev})
     busy_ns, per_plane = 0.0, {}
     modules = defaultdict(lambda: [0, 0.0])
+    plane_modules = {}
     for p in planes:
         runs = [(e.name, *_clip(e.start_ns, e.start_ns + e.dur_ns, lo, hi))
                 for e in dev if e.plane == p]
+        mine = plane_modules[p] = defaultdict(lambda: [0, 0.0])
         for name, s, t in runs:
-            modules[name][0] += 1
-            modules[name][1] += (t - s) * 1e-9
+            for tally in (modules[name], mine[name]):
+                tally[0] += 1
+                tally[1] += (t - s) * 1e-9
         per_plane[p] = union((s, t) for _, s, t in runs)
         busy_ns += sum(t - s for s, t in per_plane[p])
 
@@ -130,16 +137,22 @@ def summarize(events) -> Summary:
         busy_s=busy_ns * 1e-9 / n_dev,
         devices=len(planes),
         modules={k: list(v) for k, v in modules.items()},
-        device_ops=[[k, v[1]] for k, v in
+        device_ops=[[k, v[1] / n_dev] for k, v in
                     sorted(modules.items(), key=lambda kv: -kv[1][1])[:TOP]],
         idle_gaps=[[k, v / n_dev] for k, v in
                    sorted(gaps.items(), key=lambda kv: -kv[1])[:TOP]],
-        requests=requests)
+        requests=requests,
+        plane_modules={p: {k: list(v) for k, v in m.items()}
+                       for p, m in plane_modules.items()})
 
 
 def program_modules(s: Summary) -> tuple[int, float]:
     """(executions, device seconds) of the program's modules in the
-    window: every module but the benchmark's own."""
-    runs = [v for k, v in s.modules.items()
-            if not any(k.startswith(own) for own in OWN_MODULES)]
-    return sum(v[0] for v in runs), sum(v[1] for v in runs)
+    window, every module but the benchmark's own, on the busiest device
+    plane: the one with the most such seconds (then executions)."""
+    best = (0.0, 0)
+    for mods in s.plane_modules.values():
+        runs = [v for k, v in mods.items()
+                if not any(k.startswith(own) for own in OWN_MODULES)]
+        best = max(best, (sum(v[1] for v in runs), sum(v[0] for v in runs)))
+    return best[1], best[0]
