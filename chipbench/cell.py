@@ -3,12 +3,23 @@
 The config file holds the deployment: its groups of devices (chain,
 platform, count and each group's own scenario: deadline, ε and the
 per-device share of the uplink), the channel and the planner's
-settings. The traffic file names the entry a client calls:
+settings. A group's chain is the paper's table columns, or, where the
+config names a ``"kind"``, what that kind's files say:
+
+- ``chipbench/deployments/<kind>.py`` gives ``device_specs(config)``,
+  the groups' ``DeviceSpec``s in config order;
+- ``chipbench/references/<kind>.py`` is its plain reference, in the
+  interface of ``chipbench.reference`` (see ``chipbench.check``).
+
+The traffic file names the entry a client calls:
 
 - ``"entry": "plan_sharded"``: ``Planner.plan_sharded`` of the whole
   fleet on a ``planner_mesh`` of the cell's chips (its lanes split
   across them), every device held to its group's deadline and ε, the
-  groups' shares of the uplink pooled into one budget.
+  groups' shares of the uplink pooled into one budget;
+- any other name: ``chipbench/entries/<entry>.py``, which gives
+  ``call(cell, gains)``, the planner's result, and ``fetch(result)``,
+  its ``Answer`` on the host.
 
 Request ``i`` draws a fresh fleet (link gains) from ``(seed, i)`` on the
 first chip and makes one call; its plan is synced and fetched to the
@@ -23,7 +34,9 @@ from typing import NamedTuple
 
 import numpy as np
 
-ENTRIES = ("plan_sharded",)
+from chipbench import run
+
+BUILT_IN_ENTRY = "plan_sharded"
 
 
 class Scenario(NamedTuple):
@@ -55,6 +68,51 @@ def scenario(config: dict) -> Scenario:
                     sum(int(g["count"]) * share(g) for g in groups))
 
 
+def device_specs(config: dict) -> tuple:
+    """The deployment's groups, ``DeviceSpec``s in config order: those of
+    the config's kind, or the paper's table columns where it names none."""
+    if "kind" in config:
+        return run.load_module("deployments", config["kind"]).device_specs(config)
+    from repro.configs.paper_tables import build_chain
+    from repro.core import DeviceSpec
+
+    groups = []
+    for gr in config["groups"]:
+        ch, pl = gr["chain"], gr["platform"]
+        groups.append(DeviceSpec(
+            chain=build_chain(ch["d_mb"], ch["w_gflops"], ch["g_eff"],
+                              ch["v_loc_ms2"], ch["vm_full_s"], ch["vm_cv"]),
+            kappa=pl["kappa"], f_min_hz=pl["f_min_hz"],
+            f_max_hz=pl["f_max_hz"], p_tx_w=pl["p_tx_w"],
+            count=int(gr["count"]), name=gr["name"]))
+    return tuple(groups)
+
+
+def plan_sharded(cell, gains):
+    """The built-in entry: one ``plan_sharded`` of the cell's fleet."""
+    return cell.planner.plan_sharded(cell.spec, cell.plan_scenario,
+                                     gains=gains, mesh=cell.mesh)
+
+
+def fetch_plan(p) -> Answer:
+    """A ``Plan``'s answer, synced and copied to the host."""
+    import jax
+
+    m, b, f, feas, e, st = jax.device_get(
+        (p.m_sel, p.alloc.b, p.alloc.f, p.feasible, p.total_energy, p.status))
+    return Answer(m=np.asarray(m), b=np.asarray(b), f=np.asarray(f),
+                  feasible=np.asarray(feas), total_energy=float(e),
+                  status=int(st))
+
+
+def entry(name: str):
+    """``(call, fetch)`` of the traffic's entry ``name``."""
+    if name == BUILT_IN_ENTRY:
+        return plan_sharded, fetch_plan
+    mod = run.load_module("entries", name)
+    return mod.call, mod.fetch
+
+
 def _span(name: str, on: bool):
     if not on:
         return contextlib.nullcontext()
@@ -69,29 +127,16 @@ class Cell:
     until the first request."""
 
     def __init__(self, config: dict, traffic: dict, seed: int, devices):
-        from repro.configs.paper_tables import build_chain
-        from repro.core import DeviceSpec, FleetSpec, Planner, PlannerConfig
+        from repro.core import FleetSpec, Planner, PlannerConfig
         from repro.core import Scenario as PlanScenario
         from repro.parallel.sharding import planner_mesh
 
-        if traffic["entry"] not in ENTRIES:
-            raise ValueError(f"traffic entry {traffic['entry']!r} is not one "
-                             f"of {ENTRIES}")
         self.config, self.traffic, self.seed = config, traffic, seed
         self.devices = list(devices)
+        self.call, self.fetch = entry(traffic["entry"])
         self.scenario = scenario(config)
-        groups = []
-        for gr in config["groups"]:
-            ch, pl = gr["chain"], gr["platform"]
-            groups.append(DeviceSpec(
-                chain=build_chain(ch["d_mb"], ch["w_gflops"], ch["g_eff"],
-                                  ch["v_loc_ms2"], ch["vm_full_s"],
-                                  ch["vm_cv"]),
-                kappa=pl["kappa"], f_min_hz=pl["f_min_hz"],
-                f_max_hz=pl["f_max_hz"], p_tx_w=pl["p_tx_w"],
-                count=int(gr["count"]), name=gr["name"]))
         ch = config["channel"]
-        self.spec = FleetSpec(tuple(groups), area_m=ch["area_m"],
+        self.spec = FleetSpec(device_specs(config), area_m=ch["area_m"],
                               min_dist_m=ch["min_dist_m"])
         pl = config["planner"]
         self.planner = Planner(PlannerConfig(
@@ -112,23 +157,17 @@ class Cell:
         return fleetgen.gains_for(self.config, self.seed, i, self.devices[0])
 
     def request(self, i: int) -> Answer:
-        """Draw request ``i``'s fleet, plan it, fetch the plan."""
-        import jax
-
+        """Draw request ``i``'s fleet, plan it with the traffic's entry,
+        fetch the plan."""
         on = self.trace_spans
         t0 = time.perf_counter()
         with _span("chipbench.draw", on):
             gains = self.gains(i)
         t1 = time.perf_counter()
         with _span("chipbench.plan", on):
-            p = self.planner.plan_sharded(self.spec, self.plan_scenario,
-                                          gains=gains, mesh=self.mesh)
+            p = self.call(self, gains)
         t2 = time.perf_counter()
         with _span("chipbench.fetch", on):
-            m, b, f, feas, e, st = jax.device_get(
-                (p.m_sel, p.alloc.b, p.alloc.f, p.feasible, p.total_energy,
-                 p.status))
+            ans = self.fetch(p)
         self.last_split = (t1 - t0, t2 - t1, time.perf_counter() - t2)
-        return Answer(m=np.asarray(m), b=np.asarray(b), f=np.asarray(f),
-                      feasible=np.asarray(feas), total_energy=float(e),
-                      status=int(st))
+        return ans

@@ -1,5 +1,16 @@
 """The comparison that decides ``correct``: the program's plans against the
-plain reference (``chipbench.reference``), number by number.
+plain reference of the deployment's kind, number by number.
+
+The reference is ``chipbench.reference`` for the paper's table columns
+(a config that names no ``"kind"``), and ``chipbench/references/<kind>.py``
+for a kind of its own. Either imports nothing of the program and gives
+
+- ``deployment(config, gains, dtype=np.float64)``: the per-device
+  columns of the config's fleet for the link gains of R requests;
+- ``plan(dep, deadlines, epss, Bs, outer_iters, multi_start)``: the
+  plans of every request's fleet under K scenarios (``RefPlan``);
+- ``constraint_excess(dep, snr_b, m, b, f, deadline, eps)``: each
+  device's deadline excess at its plan, relative to its D.
 
 For every checked request the reference plans the same deployment from
 the same link gains, in float64 on the host, and the comparison reads:
@@ -9,8 +20,8 @@ the same link gains, in float64 on the host, and the comparison reads:
 - ``energy_rel_gap``: |E − E_ref| / E_ref of the plan's total energy;
 - ``constraint_excess``: how far the plan breaks its own constraints,
   evaluated in float64 by the reference's formulas: the largest
-  deadline-margin excess over the device's D, or Σb over B, whichever
-  is larger (0 when every constraint holds).
+  deadline excess of a device the reference plans feasible, over its D,
+  or Σb over B, whichever is larger (0 when every constraint holds).
 
 Each number is the largest over the checked requests. The limits sit
 between the largest reading of sound runs of the program on a TPU v5e
@@ -22,7 +33,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from chipbench import reference
+from chipbench import reference, run
 
 #: number -> limit (a run is correct when every number is ≤ its limit)
 LIMITS = {
@@ -36,23 +47,27 @@ LIMITS = {
 }
 
 
-def constraint_excess(dep: reference.Deployment, snr_b, m, b, f, deadline,
-                      eps, B, feasible):
-    """The plan's worst constraint excess, relative: deadline margins of
-    the ``feasible`` devices over each one's D, and Σb over B, in
-    float64."""
-    take = lambda a: np.take_along_axis(a, np.asarray(m)[:, None], 1)[:, 0]
-    rate = reference.rate(b, snr_b)
-    t = (take(dep.w) / (take(dep.g) * f) + take(dep.d) / rate + take(dep.t_vm)
-         + reference.sigma(eps) * np.sqrt(take(dep.v)))
-    late = np.where(feasible, (t - deadline) / deadline, 0.0)
+def reference_of(config: dict):
+    """The plain reference of the config's deployment kind."""
+    if "kind" in config:
+        return run.load_module("references", config["kind"])
+    return reference
+
+
+def constraint_excess(ref_mod, dep, snr_b, m, b, f, deadline, eps, B,
+                      feasible):
+    """The plan's worst constraint excess, relative: the deadline excess
+    of the ``feasible`` devices by the reference ``ref_mod``'s formula,
+    and Σb over B, in float64."""
+    late = np.where(feasible, ref_mod.constraint_excess(
+        dep, snr_b, m, b, f, deadline, eps), 0.0)
     return max(0.0, float(np.max(late)), float(b.sum() / B - 1.0))
 
 
-def compare(ref: reference.RefPlan, r: int, dep, ans, sc) -> dict:
+def compare(ref_mod, ref: reference.RefPlan, r: int, dep, ans, sc) -> dict:
     """The numbers of one request's plan (``ans``, a ``cell.Answer``)
     against the reference plan of fleet ``r`` of the same deployment
-    under the scenario ``sc``."""
+    under the scenario ``sc``, by the reference ``ref_mod``."""
     m = np.asarray(ans.m)
     b, f = np.asarray(ans.b, np.float64), np.asarray(ans.f, np.float64)
     rm, rf = ref.m[r, 0], ref.feasible[r, 0]
@@ -62,7 +77,7 @@ def compare(ref: reference.RefPlan, r: int, dep, ans, sc) -> dict:
         "energy_rel_gap": abs(float(ans.total_energy)
                               / float(ref.total_energy[r, 0]) - 1.0),
         "constraint_excess": constraint_excess(
-            dep, dep.snr_b[r], m, b, f, sc.deadline_s, sc.eps,
+            ref_mod, dep, dep.snr_b[r], m, b, f, sc.deadline_s, sc.eps,
             sc.bandwidth_hz, rf),
     }
 
@@ -94,10 +109,11 @@ def check_answers(config: dict, sc, answers: dict, gains_of) -> dict:
     the reference plans every request at once, each start in a process
     of its own."""
     keys = list(answers)
-    dep = reference.deployment(config, np.stack([gains_of(i) for i in keys]))
+    ref_mod = reference_of(config)
+    dep = ref_mod.deployment(config, np.stack([gains_of(i) for i in keys]))
     pl = config["planner"]
-    ref = reference.plan(dep, [sc.deadline_s], [sc.eps], [sc.bandwidth_hz],
-                         outer_iters=pl["outer_iters"],
-                         multi_start=pl["multi_start"])
-    return worst([compare(ref, r, dep, answers[i], sc)
+    ref = ref_mod.plan(dep, [sc.deadline_s], [sc.eps], [sc.bandwidth_hz],
+                       outer_iters=pl["outer_iters"],
+                       multi_start=pl["multi_start"])
+    return worst([compare(ref_mod, ref, r, dep, answers[i], sc)
                   for r, i in enumerate(keys)])

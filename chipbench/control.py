@@ -24,21 +24,22 @@ import sys
 
 import numpy as np
 
-from chipbench import check, reference, run
+from chipbench import check, run
 from chipbench.cell import Answer
 
 
 def control_answers(config: dict, sc, gains: dict) -> dict:
     """The control's plans of the requests whose gains are ``gains``
     ({request index: (N,) gains}) under the scenario ``sc``: the
-    reference in float32."""
+    reference of the config's kind in float32."""
     keys = list(gains)
-    dep = reference.deployment(config, np.stack([gains[i] for i in keys]),
-                               np.float32)
+    ref_mod = check.reference_of(config)
+    dep = ref_mod.deployment(config, np.stack([gains[i] for i in keys]),
+                             np.float32)
     pl = config["planner"]
-    p = reference.plan(dep, [sc.deadline_s], [sc.eps], [sc.bandwidth_hz],
-                       outer_iters=pl["outer_iters"],
-                       multi_start=pl["multi_start"])
+    p = ref_mod.plan(dep, [sc.deadline_s], [sc.eps], [sc.bandwidth_hz],
+                     outer_iters=pl["outer_iters"],
+                     multi_start=pl["multi_start"])
     return {i: Answer(m=p.m[r, 0], b=p.b[r, 0], f=p.f[r, 0],
                       feasible=p.feasible[r, 0],
                       total_energy=float(p.total_energy[r, 0]), status=0)

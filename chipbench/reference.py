@@ -249,6 +249,16 @@ def partition(dep: Deployment, b, f, deadline, eps):
     return m, np.take_along_axis(ok, m[..., None], -1)[..., 0]
 
 
+def constraint_excess(dep: Deployment, snr_b, m, b, f, deadline, eps):
+    """Each device's deadline excess at its points ``m``, bandwidth ``b``
+    and clock ``f`` ((N,) each; link ``snr_b``), relative to its deadline:
+    (w/(g f) + d/R(b) + t̄_vm + σ(ε) √v − D) / D."""
+    take = lambda a: np.take_along_axis(a, np.asarray(m)[:, None], 1)[:, 0]
+    t = (take(dep.w) / (take(dep.g) * f) + take(dep.d) / rate(b, snr_b)
+         + take(dep.t_vm) + sigma(eps) * np.sqrt(take(dep.v)))
+    return (t - deadline) / deadline
+
+
 def starts(dep: Deployment, multi_start: bool):
     """(S, N) start points: the spread {1, P/2, 3P/4, P-2, P-1} of the
     padded width P (or full local inference alone), clamped to each

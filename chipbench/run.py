@@ -4,7 +4,11 @@
 
 run from the root of a checkout. The cell (an entry of ``workloads`` in
 ``BENCHMARK.json``) names a deployment, ``chipbench/configs/<config>.json``,
-and a traffic mix, ``chipbench/traffic/<traffic>.json``. The run
+and a traffic mix, ``chipbench/traffic/<traffic>.json``. A deployment of
+a ``kind`` of its own brings its fleet builder and its plain reference
+as files (``chipbench/deployments/``, ``chipbench/references/``), and a
+traffic mix names its entry, built in or a file (``chipbench/entries/``):
+see ``chipbench.cell``. The run
 
 1. exits with code 2, before any work, where JAX finds no TPU or fewer
    chips than the cell asks for;
@@ -18,10 +22,11 @@ and a traffic mix, ``chipbench/traffic/<traffic>.json``. The run
    ``plan_s`` is the seconds from the window's start to the end of the
    last request begun inside it, over the requests completed;
 4. with ``--trace 1``, profiles the first ``trace_requests`` requests of
-   the window (of the traffic file) and reduces the trace to the cell's
-   per-layer metrics (``chipbench/metrics/<metric>.py``);
+   the window (of the traffic file) and reduces the trace, the device's
+   executions and the program's own spans, to the cell's per-layer
+   metrics (``chipbench/metrics/<metric>.py``);
 5. compares a sample of the window's plans, drawn from the seed, with the
-   plain reference (``chipbench.check``).
+   plain reference of the deployment's kind (``chipbench.check``).
 
 A cell runs on the first ``chips`` devices that JAX lists, the planner's
 lanes split across all of them. Per-chip readings are of the fullest
@@ -76,14 +81,15 @@ def _since_process_start() -> float:
 
 
 # ---------------------------------------------------------------------------
-# lookup by name: every config, traffic mix and per-layer metric is a file
+# lookup by name: every config, traffic mix, per-layer metric, deployment
+# kind, reference and entry is a file
 # ---------------------------------------------------------------------------
 
 def _lookup(kind: str, name: str, ext: str) -> str:
     path = os.path.join(HERE, kind, name + ext)
     if not os.path.isfile(path):
         known = sorted(f[: -len(ext)] for f in os.listdir(os.path.join(HERE, kind))
-                       if f.endswith(ext))
+                       if f.endswith(ext) and not f.startswith("_"))
         raise LookupError(f"no {kind} file {name!r}; known: {known}")
     return path
 
@@ -106,6 +112,17 @@ def load_metric(name: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+def load_module(kind: str, name: str):
+    """``chipbench/<kind>/<name>.py``: a deployment kind's program side
+    (``deployments``), its plain reference (``references``) or a traffic
+    entry (``entries``), imported as ``chipbench.<kind>.<name>`` so that
+    the reference's worker processes import it too."""
+    if not name.isidentifier():
+        raise LookupError(f"{kind} name {name!r} is not a Python identifier")
+    _lookup(kind, name, ".py")
+    return importlib.import_module(f"chipbench.{kind}.{name}")
 
 
 def load_benchmark(root: str = ROOT) -> dict:
@@ -290,7 +307,7 @@ def run_cell(cell_entry: dict, seed: int, seconds: float, trace: bool,
     """One run of a cell on ``devices`` (a list); returns the result
     object (the caller has checked the devices and configured JAX)."""
     from chipbench import cell as cell_mod
-    from chipbench import check, trace as trace_mod
+    from chipbench import check, spans as spans_mod, trace as trace_mod
 
     clock = CompileClock()
     config = load_config(cell_entry["config"])
@@ -343,7 +360,8 @@ def run_cell(cell_entry: dict, seed: int, seconds: float, trace: bool,
     if trace:
         path = _find_xplane(trace_dir)
         xplane_bytes = os.path.getsize(path)
-        summary = trace_mod.summarize(trace_mod.read_events(path))
+        events = trace_mod.read_events(path)
+        summary = trace_mod.summarize(events)
         shutil.rmtree(trace_dir, ignore_errors=True)
         for m in cell_entry["per_layer"]:
             v = readers[m["name"]](summary)
@@ -351,10 +369,12 @@ def run_cell(cell_entry: dict, seed: int, seconds: float, trace: bool,
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
         device_info.update(busy_s=summary.busy_s, window_s=summary.window_s)
         breakdown = {"device_ops": summary.device_ops,
-                     "idle_gaps": summary.idle_gaps}
+                     "idle_gaps": summary.idle_gaps,
+                     "program_idle_gaps": spans_mod.program_idle_gaps(events)}
         print(json.dumps({"trace": {"requests": summary.requests,
                                     "xplane_bytes": xplane_bytes,
-                                    "modules": summary.modules}}),
+                                    "modules": summary.modules,
+                                    "spans": summary.spans}}),
               file=out, flush=True)
     else:
         values = {"setup_s": setup_s, "plan_s": span_s / n if n else None}

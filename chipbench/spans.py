@@ -21,23 +21,14 @@ its copy back. From the events of a trace
 - per plan request: the probes of the price loop, the milliseconds it
   waited on the device, and its host milliseconds besides the waits.
 
-``chipbench.run`` does not read these yet: its ``Summary`` carries no
-span table. On the chip,
-
-    python3 -m chipbench.spans --workload <cell> --seed <n> --seconds <s>
-
-run from the root of a checkout, sets up as ``chipbench.run`` does,
-traces the cell's first ``trace_requests`` requests, runs the rest of
-the window untraced, and prints one JSON line: the trace's numbers
-(those of the accepted readers and these) and every request's seconds.
+``chipbench.trace.summarize`` puts the table in ``Summary.spans``, where
+the per-layer readers ``price_probes_per_plan``, ``price_wait_ms`` and
+``price_host_ms`` (``chipbench/metrics/``) take it; a traced run of
+``chipbench.run`` prints it on its ``trace`` line and gives
+``program_idle_gaps`` in its ``breakdown``.
 """
 from __future__ import annotations
 
-import argparse
-import json
-import shutil
-import sys
-import tempfile
 from collections import defaultdict
 
 from chipbench import trace
@@ -150,58 +141,3 @@ def price_host_ms(spans: dict, requests: int):
     if not requests or not clearing_s:
         return None
     return (clearing_s - spans.get(WAIT, [0, 0.0])[1]) * 1e3 / requests
-
-
-def reduce(events) -> dict:
-    """Every number this module and the accepted readers take from a
-    trace, per plan request where a reader is."""
-    from chipbench import run
-
-    s = trace.summarize(events)
-    spans = table(events)
-    out = {"requests": s.requests, "window_s": s.window_s, "busy_s": s.busy_s}
-    for name in ("launches_per_plan", "plan_program_ms", "idle_share.plan"):
-        out[name] = run.load_metric(name)(s)
-    for fn in (price_probes_per_plan, price_wait_ms, price_host_ms):
-        out[fn.__name__] = fn(spans, s.requests)
-    out.update(spans=spans, device_ops=s.device_ops, idle_gaps=s.idle_gaps,
-               program_idle_gaps=program_idle_gaps(events))
-    return out
-
-
-def main(argv=None) -> int:
-    from chipbench import run
-
-    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
-    ap.add_argument("--workload", required=True)
-    ap.add_argument("--seed", type=int, required=True)
-    ap.add_argument("--seconds", type=float, required=True)
-    args = ap.parse_args(argv)
-
-    started = run.start(args.workload)
-    if isinstance(started, int):
-        return started
-    cell_entry, devices = started
-    from chipbench import cell as cell_mod
-
-    traffic = run.load_traffic(cell_entry["traffic"])
-    cell = cell_mod.Cell(run.load_config(cell_entry["config"]), traffic,
-                         args.seed, devices)
-    cell.request(-1 & 0x7FFFFFFF)  # warm-up, as chipbench.run makes it
-    trace_dir = tempfile.mkdtemp(prefix="chipbench_spans_")
-    n_trace = int(traffic["trace_requests"])
-    _, _, took = run.run_window(cell, args.seconds, n_trace, trace_dir)
-    events = trace.read_events(run._find_xplane(trace_dir))
-    shutil.rmtree(trace_dir, ignore_errors=True)
-    out = reduce(events)
-    out.update(traced_requests=n_trace,
-               request_s=[r["s"] for r in took],
-               device={"platform": devices[0].platform,
-                       "kind": devices[0].device_kind,
-                       "count": len(devices)})
-    print(json.dumps(out), flush=True)
-    return 0
-
-
-if __name__ == "__main__":
-    sys.exit(main())

@@ -16,7 +16,8 @@ def test_every_cell_resolves(bench):
         cell = run.cell_of(bench, w["name"])
         config = run.load_config(cell["config"])
         assert config["name"] == cell["config"]
-        assert run.load_traffic(cell["traffic"])["entry"] in cell_mod.ENTRIES
+        call, fetch = cell_mod.entry(run.load_traffic(cell["traffic"])["entry"])
+        assert callable(call) and callable(fetch)
         e2e = [m["name"] for m in cell["end_to_end"]]
         assert "setup_s" in e2e and len(e2e) >= 2, e2e
         assert cell["per_layer"], w["name"]

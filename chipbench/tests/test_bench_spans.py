@@ -4,7 +4,7 @@ import os
 
 import pytest
 
-from chipbench import spans, trace
+from chipbench import run, spans, trace
 from chipbench.trace import Event
 
 DEV, HOST = "/device:TPU:0", "/host:CPU"
@@ -77,9 +77,16 @@ def test_price_loop_numbers_by_hand():
     assert spans.price_wait_ms(tab, requests) == pytest.approx(1300e-6 / 2)
     # lam 3500 + mu 2800 less the waits 1300
     assert spans.price_host_ms(tab, requests) == pytest.approx(5000e-6 / 2)
-    r = spans.reduce(ev)
-    assert r["price_probes_per_plan"] == 2.0
-    assert r["launches_per_plan"] == 2.0  # 4 bsum executions, 2 requests
+    s = trace.summarize(ev)
+    assert s.spans == tab
+    read = lambda name: run.load_metric(name)(s)
+    assert read("price_probes_per_plan") == 2.0
+    assert read("price_wait_ms") == pytest.approx(1300e-6 / 2)
+    assert read("price_host_ms") == pytest.approx(5000e-6 / 2)
+    assert read("launches_per_plan") == 2.0  # 4 bsum executions, 2 requests
+
+
+READERS = ("price_probes_per_plan", "price_wait_ms", "price_host_ms")
 
 
 def test_nothing_to_read_in_an_empty_window():
@@ -89,6 +96,37 @@ def test_nothing_to_read_in_an_empty_window():
     for fn in (spans.price_probes_per_plan, spans.price_wait_ms,
                spans.price_host_ms):
         assert fn(tab, 0) is None and fn(tab, 3) is None, fn.__name__
+
+
+@pytest.mark.parametrize("name", READERS)
+def test_reader_finds_nothing_without_probes(name):
+    """The program's plan and steps in the window, but no price loop: no
+    clearing, probe or wait."""
+    loop = (spans.PROBE, spans.WAIT, *spans.CLEARINGS)
+    s = trace.summarize([e for e in _events() if e.name not in loop])
+    assert s.requests == 2 and sorted(s.spans) == ["repro.plan_sharded",
+                                                   "repro.step"]
+    assert run.load_metric(name)(s) is None
+
+
+@pytest.mark.parametrize("name", READERS)
+def test_reader_by_hand(name):
+    s = trace.summarize(_events())
+    want = {"price_probes_per_plan": 2.0,
+            # the waits 400 + 500 + 400 ns over 2 requests
+            "price_wait_ms": 1300e-6 / 2,
+            # lam 3500 + mu 2800 less the waits 1300
+            "price_host_ms": 5000e-6 / 2}[name]
+    assert run.load_metric(name)(s) == pytest.approx(want)
+    # nothing to read without a plan request in the window
+    assert run.load_metric(name)(s._replace(requests=0)) is None
+
+
+@pytest.mark.parametrize("name", READERS)
+def test_reader_finds_nothing_in_an_empty_window(name):
+    s = trace.summarize([Event(HOST, "python", "chipbench.window", 0, 1000)])
+    assert s.spans == {}
+    assert run.load_metric(name)(s) is None
 
 
 def test_one_window_span_required():
@@ -105,6 +143,8 @@ def test_recorded_chip_trace_has_no_program_spans():
     events = trace.read_events(path)
     tab = spans.table(events)
     assert tab == {}
+    # the Summary carries the table, empty and not missing
+    assert trace.summarize(events).spans == {}
     for fn in (spans.price_probes_per_plan, spans.price_wait_ms,
                spans.price_host_ms):
         assert fn(tab, 2) is None, fn.__name__

@@ -14,7 +14,9 @@ From the trace this module takes, inside that window:
   busiest plane, the chip that sets the pace (``program_modules``), and
   the breakdown's are averaged over the planes;
 - the idle gaps between busy intervals, each labelled by the host span
-  in which its midpoint fell (``host`` where none did), summed by label.
+  in which its midpoint fell (``host`` where none did), summed by label;
+- the program's own host spans, ``repro.*``, as ``chipbench.spans.table``
+  counts them: a name's count, seconds and self seconds.
 
 The per-op events (``XLA Ops``, millions for one plan: every step of
 every loop) are not read. Events are read with
@@ -53,6 +55,7 @@ class Summary(NamedTuple):
     idle_gaps: list  # [[host span, seconds a plane]], most idle time first
     requests: int  # chipbench.plan spans that began inside the window
     plane_modules: dict  # device plane -> {module name: [executions, s]}
+    spans: dict  # program span name -> [count, seconds, self seconds]
 
 
 def read_events(path: str) -> list[Event]:
@@ -91,6 +94,8 @@ def _clip(s, e, lo, hi):
 
 def summarize(events) -> Summary:
     """The per-layer numbers of the traced window in ``events``."""
+    from chipbench import spans as program_spans
+
     windows = [e for e in events if e.name == WINDOW_SPAN]
     if len(windows) != 1:
         raise ValueError(f"expected one {WINDOW_SPAN} span, found {len(windows)}")
@@ -143,7 +148,8 @@ def summarize(events) -> Summary:
                    sorted(gaps.items(), key=lambda kv: -kv[1])[:TOP]],
         requests=requests,
         plane_modules={p: {k: list(v) for k, v in m.items()}
-                       for p, m in plane_modules.items()})
+                       for p, m in plane_modules.items()},
+        spans=program_spans.table(events))
 
 
 def program_modules(s: Summary) -> tuple[int, float]:
